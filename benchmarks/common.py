@@ -2,14 +2,16 @@
 
 Sizes are scaled to a single CPU core (the paper runs 1M vectors on a
 144-thread Xeon); every benchmark keeps the paper's *structure* — same
-workloads, same comparisons, same metrics — at reduced N.  The TPU-scale
-path is exercised by the dry-run + roofline instead (EXPERIMENTS.md).
+workloads, same comparisons, same metrics — at reduced N.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from pathlib import Path
+
+import jax
 
 import numpy as np
 
@@ -17,6 +19,21 @@ from repro.core import (LabelWorkloadConfig, brute_force_filtered,
                         generate_label_sets, generate_query_label_sets,
                         recall_at_k)
 from repro.obs import metrics as obs_metrics
+
+
+@contextlib.contextmanager
+def cold_compiles():
+    """Every program the block runs is compiled anew: the in-memory jit
+    caches are cleared and the persistent compilation cache is off for the
+    block.  Cold-start measurements run in process this way — a child
+    process could not reach the accelerator the parent holds."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
 
 
 def latency_percentiles(lat_s: list[float]) -> dict:

@@ -15,9 +15,8 @@ Four measurements land in ``BENCH_exp10.json``:
     is the acceptance's "compaction ≫ faster than full rebuild".
   * ``warmup`` — cold-start shrinkage of the FIRST post-insert batch after
     ``StreamingEngine.warmup`` pre-traced the tombstone-fused base, delta
-    -scan, and merge programs — measured in a SUBPROCESS (the exp9
-    pattern: the XLA executable cache is process-wide, an in-process
-    remeasure would silently be warm).
+    -scan, and merge programs — measured with every compile cache cleared
+    first (``common.cold_compiles``, the exp9 pattern).
   * ``delete_sweep`` (ISSUE 5) — a delete-heavy workload (delete batch →
     search batch, repeated) on PRIVATE-storage backends, lazy tombstones
     (``lazy_deletes=True``, the default: per-index bitmaps through
@@ -32,9 +31,6 @@ temp dir (unless the caller routes it with an explicit ``out_dir`` — the
 CI bench-smoke job uploads that directory as a workflow artifact) so a
 smoke run never clobbers the recorded perf artifact.
 """
-import json
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -44,36 +40,7 @@ from repro.core import LabelHybridEngine, LabelWorkloadConfig, StreamingEngine
 from repro.core import generate_label_sets
 from repro.index.base import pow2_bucket
 
-from .common import emit, emit_json, ground_truth, make_dataset
-
-_WARMUP_CHILD = r"""
-import json, time
-import numpy as np
-from benchmarks.common import make_dataset
-from benchmarks.exp10_streaming import insert_pool
-from repro.core import StreamingEngine
-from repro.index.base import pow2_bucket
-
-n, k, q, warm = json.loads({spec!r})
-x, ls, qv, qls = make_dataset(n=n, n_labels=12, q=q, seed=7)
-px, pls = insert_pool(n // 10, x.shape[1], seed=29)
-se = StreamingEngine.build(x, ls, mode="eis", c=0.2, backend="flat",
-                           max_delta_fraction=None,
-                           max_tombstone_fraction=None,
-                           min_delta_capacity=pow2_bucket(n // 10))
-warmup_s, programs = 0.0, 0
-if warm:
-    rep = se.warmup([k], [pow2_bucket(q)])
-    warmup_s, programs = rep["seconds"], rep["programs"]
-se.insert(px, pls)                       # first mutation AFTER warmup
-se.delete(np.arange(0, n, 97))
-t0 = time.perf_counter()
-se.search_batched(qv, qls, k, min_bucket=pow2_bucket(q))
-cold_after = time.perf_counter() - t0
-print("RESULT" + json.dumps({{"warmup_s": warmup_s, "programs": programs,
-                              "first_mutated_batch_s": cold_after}}))
-"""
-
+from .common import cold_compiles, emit, emit_json, ground_truth, make_dataset
 
 def insert_pool(m: int, d: int, seed: int = 29):
     """Held-out rows to stream in (same label universe as the base)."""
@@ -138,16 +105,24 @@ def _measure_qps(searcher, qv, qls, k, repeats=3):
 
 
 def _measure_warmup(n: int, k: int, q: int, warm: bool) -> dict:
-    spec = json.dumps([n, k, q, warm])
-    child = _WARMUP_CHILD.format(spec=spec)
-    r = subprocess.run([sys.executable, "-c", child], capture_output=True,
-                       text=True, cwd=".")
-    line = next((ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")),
-                None)
-    if line is None:
-        print(r.stdout[-2000:], r.stderr[-2000:])
-        raise RuntimeError("exp10 warmup child failed")
-    return json.loads(line[len("RESULT"):])
+    with cold_compiles():
+        x, ls, qv, qls = make_dataset(n=n, n_labels=12, q=q, seed=7)
+        px, pls = insert_pool(n // 10, x.shape[1], seed=29)
+        se = StreamingEngine.build(x, ls, mode="eis", c=0.2, backend="flat",
+                                   max_delta_fraction=None,
+                                   max_tombstone_fraction=None,
+                                   min_delta_capacity=pow2_bucket(n // 10))
+        warmup_s, programs = 0.0, 0
+        if warm:
+            rep = se.warmup([k], [pow2_bucket(q)])
+            warmup_s, programs = rep["seconds"], rep["programs"]
+        se.insert(px, pls)                   # first mutation AFTER warmup
+        se.delete(np.arange(0, n, 97))
+        t0 = time.perf_counter()
+        se.search_batched(qv, qls, k, min_bucket=pow2_bucket(q))
+        cold_after = time.perf_counter() - t0
+    return {"warmup_s": warmup_s, "programs": programs,
+            "first_mutated_batch_s": cold_after}
 
 
 def run(n=4_000, k=10, out_dir=None, measure_warmup=True, tiny=False):
@@ -251,7 +226,7 @@ def run(n=4_000, k=10, out_dir=None, measure_warmup=True, tiny=False):
                      "qps_fold": f"{res['fold_per_delete']['qps']:.0f}",
                      "lazy_speedup": f"{res['lazy_speedup']:.1f}"})
 
-    # -- warmup: first post-insert batch, subprocess-isolated --------------
+    # -- warmup: first post-insert batch, compiled cold ---------------------
     if measure_warmup:
         wu = _measure_warmup(n, k, q, warm=True)
         nowu = _measure_warmup(n, k, q, warm=False)

@@ -1,32 +1,22 @@
 """Exp-3 — thread scaling becomes shard scaling on the TPU mesh.
 
-Runs in a subprocess with 8 fake host devices; the DistributedFlatIndex
-shards rows over the 'data' axis and merges per-shard top-k with one
-all-gather.  Reported: per-shard-count QPS + recall (merge correctness) +
-the collective payload (2·S·k·8 bytes per query — N-independent).
+The DistributedFlatIndex shards rows over the 'data' axis of a mesh of
+1, 2, 4, 8 devices (as many as exist: on a CPU host run under
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``) and merges
+per-shard top-k with one all-gather.  Runs in this process.  Reported:
+per-shard-count QPS + recall (merge correctness) + the collective payload
+(2·S·k·8 bytes per query — N-independent).
 """
-import json
-import subprocess
-import sys
-
-from .common import emit
-
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, time
-import numpy as np
 import jax
-from benchmarks.common import make_dataset, ground_truth, measure
+
+from repro.compat import make_mesh
 from repro.core.labels import encode_many, masks_to_int32_words
 from repro.index.distributed import DistributedFlatIndex
 
-x, ls, qv, qls = make_dataset(n=16_000, q=96)
-gt_d, gt_i = ground_truth(x, ls, qv, qls, 10)
-words = masks_to_int32_words(encode_many(ls))
+from .common import emit, ground_truth, make_dataset, measure
 
 
-class W:
+class _Searcher:
     def __init__(self, ix):
         self.ix = ix
 
@@ -34,32 +24,21 @@ class W:
         return self.ix.search(qv, masks_to_int32_words(encode_many(qls)), k)
 
 
-out = []
-for s in (1, 2, 4, 8):
-    mesh = jax.make_mesh((s,), ("data",), devices=jax.devices()[:s])
-    ix = DistributedFlatIndex(x, words, mesh)
-    qps, rec, us = measure(W(ix), qv, qls, 10, gt_i, len(ls))
-    out.append({"shards": s, "qps": round(qps), "recall": round(rec, 4),
-                "us": round(us, 1),
-                "collective_bytes_per_q": 2 * s * 10 * 8})
-print("RESULT" + json.dumps(out))
-"""
-
-
 def run():
-    r = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
-                       text=True, env=None, cwd=".")
-    line = next((ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")),
-                None)
-    if line is None:
-        print(r.stdout[-2000:], r.stderr[-2000:])
-        raise RuntimeError("exp3 child failed")
+    x, ls, qv, qls = make_dataset(n=16_000, q=96)
+    gt_d, gt_i = ground_truth(x, ls, qv, qls, 10)
+    words = masks_to_int32_words(encode_many(ls))
     rows = []
-    for rec in json.loads(line[len("RESULT"):]):
-        rows.append({"name": f"exp3/shards={rec['shards']}",
-                     "us_per_call": rec["us"], "qps": rec["qps"],
-                     "recall": rec["recall"],
-                     "collective_bytes_per_q": rec["collective_bytes_per_q"]})
+    for s in (1, 2, 4, 8):
+        if s > len(jax.devices()):
+            break
+        mesh = make_mesh((s,), ("data",), devices=jax.devices()[:s])
+        ix = DistributedFlatIndex(x, words, mesh)
+        qps, rec, us = measure(_Searcher(ix), qv, qls, 10, gt_i, len(ls))
+        rows.append({"name": f"exp3/shards={s}",
+                     "us_per_call": round(us, 1), "qps": round(qps),
+                     "recall": round(rec, 4),
+                     "collective_bytes_per_q": 2 * s * 10 * 8})
     emit(rows, "exp3")
     return rows
 

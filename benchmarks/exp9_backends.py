@@ -13,23 +13,22 @@ Three measurements land in ``BENCH_exp9.json``:
     per-key ``search_looped`` reference — cold (first call, tracing +
     compilation included) and warm (steady state);
   * ``warmup``: cold-start shrinkage from ``engine.warmup(ks, buckets)``,
-    measured in a SUBPROCESS per backend (the XLA executable cache is
-    process-wide, so an in-process remeasure would silently be warm) —
-    targets the 11.8 s distributed cold batched path recorded pre-arena;
+    measured per backend with every compile cache cleared first
+    (``common.cold_compiles``) — targets the 11.8 s distributed cold
+    batched path recorded pre-arena;
   * ``flat_sweep``: warm QPS of both executors as the selection size grows
     (c sweep) — the arena executor's launches scale with span tiers, not
     with ``n_indexes``, so its warm QPS must stay flat while the per-key
     loop degrades.
 """
-import json
-import subprocess
-import sys
 import tempfile
+import time
 
 from repro.core import LabelHybridEngine
 from repro.index.base import pow2_bucket
 
-from .common import emit, emit_json, ground_truth, make_dataset, measure_modes
+from .common import (cold_compiles, emit, emit_json, ground_truth,
+                     make_dataset, measure_modes)
 
 BACKENDS = (
     ("flat", {}),
@@ -37,27 +36,6 @@ BACKENDS = (
     ("graph", {"M": 12, "ef_search": 64}),
     ("distributed", {}),
 )
-
-_WARMUP_CHILD = r"""
-import json, time
-import numpy as np
-from benchmarks.common import make_dataset
-from benchmarks.exp9_backends import workload_buckets
-from repro.core import LabelHybridEngine
-
-backend, params, n, k = json.loads({spec!r})
-x, ls, qv, qls = make_dataset(n=n, n_labels=12, q=80, seed=7)
-eng = LabelHybridEngine.build(x, ls, mode="eis", c=0.2, backend=backend,
-                              **params)
-rep = eng.warmup([k], workload_buckets(eng, qls))
-t0 = time.perf_counter()
-eng.search_batched(qv, qls, k)
-cold_after = time.perf_counter() - t0
-print("RESULT" + json.dumps({{"warmup_s": rep["seconds"],
-                              "programs": rep["programs"],
-                              "cold_after_warmup_s": cold_after}}))
-"""
-
 
 def workload_buckets(eng, qls) -> list[int]:
     """The Q-buckets a query workload will induce: per span tier on the
@@ -76,23 +54,23 @@ def workload_buckets(eng, qls) -> list[int]:
 
 
 def _measure_warmup(backend: str, params: dict, n: int, k: int) -> dict:
-    spec = json.dumps([backend, params, n, k])
-    child = _WARMUP_CHILD.format(spec=spec)
-    r = subprocess.run([sys.executable, "-c", child], capture_output=True,
-                       text=True, cwd=".")
-    line = next((ln for ln in r.stdout.splitlines() if ln.startswith("RESULT")),
-                None)
-    if line is None:
-        print(r.stdout[-2000:], r.stderr[-2000:])
-        raise RuntimeError(f"exp9 warmup child failed for {backend}")
-    return json.loads(line[len("RESULT"):])
+    with cold_compiles():
+        x, ls, qv, qls = make_dataset(n=n, n_labels=12, q=80, seed=7)
+        eng = LabelHybridEngine.build(x, ls, mode="eis", c=0.2,
+                                      backend=backend, **params)
+        rep = eng.warmup([k], workload_buckets(eng, qls))
+        t0 = time.perf_counter()
+        eng.search_batched(qv, qls, k)
+        cold_after = time.perf_counter() - t0
+    return {"warmup_s": rep["seconds"], "programs": rep["programs"],
+            "cold_after_warmup_s": cold_after}
 
 
 def run(n=4_000, k=10, out_dir=None, measure_warmup=True, sweep=True,
         tiny=False):
     if tiny:
         # CI smoke (benchmarks.run --tiny): all four backends end to end
-        # at toy size; subprocess warmup + the sweep are full-size-only
+        # at toy size; the cold warmup + the sweep are full-size-only
         n, measure_warmup, sweep = 600, False, False
     if out_dir is None:
         # tiny runs must never clobber the recorded artifact unless the

@@ -22,6 +22,7 @@ import sys
 import time
 import traceback
 
+from repro.launch import compile_cache
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -68,6 +69,7 @@ def main() -> int:
                     help="print the Prometheus exposition after all "
                          "benchmarks finish")
     args = ap.parse_args()
+    compile_cache.enable()
     names = [n for n in args.only.split(",") if n] or list(ALL)
     if args.tiny_only:
         names = [n for n in names if tiny_capable(n)]

@@ -12,6 +12,9 @@ import sys
 from repro.core.engine import LabelHybridEngine, brute_force_filtered
 from repro.core import recall_at_k
 from repro.data.pipeline import VectorLabelDataset
+from repro.launch import compile_cache
+
+compile_cache.enable()
 
 # 1. a labelled vector dataset (Zipf label popularity, like the paper §6)
 ds = VectorLabelDataset(n=20_000, dim=32, n_labels=12, seed=0)

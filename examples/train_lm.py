@@ -12,11 +12,13 @@ import dataclasses
 from repro import arch as A
 from repro.configs import reduced_arch
 from repro.data import TokenStream
+from repro.launch import compile_cache
 from repro.optim import OptimizerConfig
 from repro.train import SimulatedFailure, TrainConfig, Trainer
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2_130m",
                     choices=A.ARCH_IDS)

@@ -1,11 +1,12 @@
-"""repro.compat — the single source of truth for drifting JAX APIs.
+"""repro.compat — the single place where drifting JAX APIs are spelled.
 
 JAX moves fast: symbols migrate between ``jax.experimental`` and the
 top-level namespace, keyword names change (``check_rep`` → ``check_vma``),
 and Pallas TPU compiler params were renamed (``TPUCompilerParams`` →
 ``CompilerParams``).  Every module in this repo that touches one of those
-APIs goes through this shim so the codebase pins to exactly one spelling
-per API, and a JAX upgrade is a one-file change.
+APIs imports it from here, so the codebase spells each API exactly once
+and a JAX upgrade is a one-file change.  Each name below is the spelling of
+the installed JAX (0.9).
 
 Policy (see ROADMAP.md): new call sites of a version-drifting JAX API MUST
 be added here first and imported from ``repro.compat`` — never spelled
@@ -13,133 +14,47 @@ directly.  ``tests/test_compat_policy.py`` greps the tree to enforce it.
 
 Covered APIs:
 
-  shard_map               top-level ``jax.shard_map`` (new) vs
-                          ``jax.experimental.shard_map.shard_map`` (old);
-                          unifies the ``check_vma``/``check_rep`` kwarg.
-  tree_flatten_with_path  ``jax.tree.flatten_with_path`` (new) vs
-                          ``jax.tree_util.tree_flatten_with_path`` (old).
-  tpu_compiler_params     ``pltpu.CompilerParams`` (new) vs
-                          ``pltpu.TPUCompilerParams`` (old).
-  make_mesh / AXIS_TYPE_AUTO
-                          ``jax.make_mesh(..., axis_types=...)`` grew the
-                          ``axis_types`` kwarg (and ``jax.sharding.AxisType``)
-                          after 0.4.x; older versions get the plain mesh.
+  shard_map               ``jax.shard_map`` (``check_vma`` kwarg).
+  tree_flatten_with_path  ``jax.tree.flatten_with_path``.
+  tpu_compiler_params     ``pltpu.CompilerParams``.
+  make_mesh               ``jax.make_mesh(..., axis_types=...)``.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable
 
 import jax
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                         # jax >= 0.6
-    _shard_map_impl = jax.shard_map
-else:                                                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool | None = None, **kwargs) -> Callable:
-    """Version-stable ``shard_map``.
-
-    ``check_vma`` is the modern name of the replication-check flag
-    (``check_rep`` before the rename); pass it here under the new name and
-    the shim translates for older JAX.
-    """
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-        # very old versions have neither: drop the flag
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# tree flatten-with-path
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "flatten_with_path"):
-    _flatten_with_path = jax.tree.flatten_with_path   # jax >= 0.4.38
-else:
-    _flatten_with_path = jax.tree_util.tree_flatten_with_path
+shard_map = jax.shard_map
 
 
 def tree_flatten_with_path(tree, is_leaf: Callable | None = None):
-    """Version-stable ``tree.flatten_with_path`` -> ([(path, leaf)], treedef)."""
-    return _flatten_with_path(tree, is_leaf=is_leaf)
+    """``jax.tree.flatten_with_path`` -> ([(path, leaf)], treedef)."""
+    return jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
 
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
 
 def tpu_compiler_params(**kwargs) -> Any:
-    """Construct Pallas TPU compiler params under either class name.
+    """``pltpu.CompilerParams(**kwargs)``, e.g.
+    ``tpu_compiler_params(dimension_semantics=("parallel", "arbitrary"))``.
 
-    e.g. ``tpu_compiler_params(dimension_semantics=("parallel", "arbitrary"))``
-
-    Pallas TPU is imported lazily: only kernel modules pay the import, and
-    non-kernel compat consumers (checkpoint, arch, launch) keep working in
-    environments where the Pallas TPU stack is unavailable.
+    Pallas TPU is imported lazily: only kernel modules pay the import.
     """
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# mesh construction with axis types
-# ---------------------------------------------------------------------------
-
-AXIS_TYPE_AUTO: Any = getattr(getattr(jax.sharding, "AxisType", None),
-                              "Auto", None)
-
-_MAKE_MESH_PARAMS = (frozenset(inspect.signature(jax.make_mesh).parameters)
-                     if hasattr(jax, "make_mesh") else frozenset())
+    return pltpu.CompilerParams(**kwargs)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None, explicit_axes=()):
-    """Version-stable ``jax.make_mesh``.
-
-    ``explicit_axes`` names mesh axes that should use Explicit sharding
-    semantics where supported; every other axis is Auto.  On JAX versions
-    without ``axis_types`` the flag is dropped (everything is Auto there,
-    which is those versions' only behavior); before ``jax.make_mesh``
-    existed at all, the mesh is built directly from the device grid.
-    """
-    if not _MAKE_MESH_PARAMS:                             # jax < 0.4.35
-        import numpy as np
-        devs = list(devices) if devices is not None else jax.devices()
-        n = int(np.prod(axis_shapes))
-        grid = np.asarray(devs[:n], dtype=object).reshape(axis_shapes)
-        return jax.sharding.Mesh(grid, axis_names)
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if "axis_types" in _MAKE_MESH_PARAMS and AXIS_TYPE_AUTO is not None:
-        axis_type = jax.sharding.AxisType
-        kwargs["axis_types"] = tuple(
-            axis_type.Explicit if n in explicit_axes else axis_type.Auto
-            for n in axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    """``jax.make_mesh`` with every axis Auto except those named in
+    ``explicit_axes``, which use Explicit sharding semantics."""
+    axis_type = jax.sharding.AxisType
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=tuple(axis_type.Explicit if n in explicit_axes
+                         else axis_type.Auto for n in axis_names),
+        devices=devices)
 
 
 __all__ = [
-    "AXIS_TYPE_AUTO",
-    "JAX_VERSION",
     "make_mesh",
     "shard_map",
     "tpu_compiler_params",

@@ -173,7 +173,7 @@ def _beam_search_batch(adj, xb, lxw, q, lq, entries, tomb=None, *, k: int,
 
     def dist_to(qr, ids):
         rows = xb[jnp.clip(ids, 0, N - 1)]
-        ip = rows @ qr
+        ip = jnp.matmul(rows, qr, precision=jax.lax.Precision.HIGHEST)
         if metric == "ip":
             return -ip
         return xb_sq[jnp.clip(ids, 0, N - 1)] - 2.0 * ip + jnp.sum(qr * qr)

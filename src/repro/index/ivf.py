@@ -44,6 +44,11 @@ from ..kernels import ref
 from .base import bucket_cache, pad_to_bucket, register_index
 
 
+def _mm(a, b):
+    """f32 matmul at full precision (a TPU's default is one bf16 pass)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 @functools.partial(jax.jit, static_argnames=("n_clusters", "iters"))
 def _kmeans(x: jnp.ndarray, n_clusters: int, iters: int, seed: int = 0):
     n, d = x.shape
@@ -52,11 +57,11 @@ def _kmeans(x: jnp.ndarray, n_clusters: int, iters: int, seed: int = 0):
     cents = x[init]
 
     def step(cents, _):
-        d2 = (jnp.sum(x * x, 1, keepdims=True) - 2 * x @ cents.T
+        d2 = (jnp.sum(x * x, 1, keepdims=True) - 2 * _mm(x, cents.T)
               + jnp.sum(cents * cents, 1)[None, :])
         assign = jnp.argmin(d2, axis=1)
         one_hot = jax.nn.one_hot(assign, n_clusters, dtype=x.dtype)
-        sums = one_hot.T @ x
+        sums = _mm(one_hot.T, x)
         counts = jnp.maximum(one_hot.sum(0)[:, None], 1.0)
         new = sums / counts
         # keep empty clusters where they were
@@ -64,7 +69,7 @@ def _kmeans(x: jnp.ndarray, n_clusters: int, iters: int, seed: int = 0):
         return new, None
 
     cents, _ = jax.lax.scan(step, cents, None, length=iters)
-    d2 = (jnp.sum(x * x, 1, keepdims=True) - 2 * x @ cents.T
+    d2 = (jnp.sum(x * x, 1, keepdims=True) - 2 * _mm(x, cents.T)
           + jnp.sum(cents * cents, 1)[None, :])
     return cents, jnp.argmin(d2, axis=1)
 

@@ -43,6 +43,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
 
+# Mosaic's tile size for a 1-D 32-bit array in HBM: a DMA may slice such an
+# array only at multiples of it (the row table's id windows, below)
+ID_ALIGN = 1024
+
 
 def resolve_fused(fused, *, backend: str) -> bool:
     """Resolve the public ``fused=True|False|"auto"`` flag to a static
@@ -154,76 +158,218 @@ def _lax_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens, tomb,
 # ---------------------------------------------------------------------------
 
 
-def _pack_tombstone_words(tomb):
-    """[⌈N/8⌉] u8 little-bit-order bitmap → [Tw, 1] i32 words such that
-    row ``r``'s bit is ``(words[r >> 5] >> (r & 31)) & 1`` — the same bit
-    indexing as ``ref.tombstone_mask``, with bytes packed little-endian
-    into each word."""
-    t = jnp.pad(tomb, (0, (-tomb.shape[0]) % 4)).astype(jnp.uint32)
-    w = (t[0::4] | (t[1::4] << 8) | (t[2::4] << 16) | (t[3::4] << 24))
-    return jax.lax.bitcast_convert_type(w, jnp.int32).reshape(-1, 1)
+SIDE_LANES = 128   # one lane tile: the width of the per-row sidecar
+
+
+def row_sidecar(alw, norms=None, scales=None, zeros=None, tomb=None,
+                codes=None):
+    """Per-row operands of the Pallas scan as one [N, SIDE_LANES] i32
+    array, plus its static lane layout ``((field, first lane, width),
+    ...)``.
+
+    Mosaic slices a 2-D HBM array in whole 128-lane tiles, and a 1-row
+    slice only where the array's tile is one row high (32-bit arrays).  So
+    narrow per-row arrays (label words, the norm, the int8 scale and
+    zero-point, liveness) and the uint8 codes of the int8 tier could not be
+    gathered row by row on their own; side by side as 32-bit lanes of one
+    row they cost one DMA per row.  ``codes`` (uint8 [N, Dp], Dp a multiple
+    of 4) are packed four to a word, little-endian: lane i holds codes 4i
+    .. 4i+3 (see :func:`pack_query`).  f32 bits ride as i32; liveness is
+    1 for a live row."""
+    n = alw.shape[0]
+    cols, layout, lane = [], [], 0
+
+    def add(name, col):
+        nonlocal lane
+        col = col.astype(jnp.int32) if col.dtype != jnp.float32 else \
+            jax.lax.bitcast_convert_type(col, jnp.int32)
+        col = col.reshape(n, -1)
+        layout.append((name, lane, col.shape[1]))
+        cols.append(col)
+        lane += col.shape[1]
+
+    if codes is not None:
+        words = jax.lax.bitcast_convert_type(
+            codes.reshape(n, -1, 4), jnp.uint32)
+        add("codes", jax.lax.bitcast_convert_type(words, jnp.int32))
+    add("labels", alw)
+    for name, col in (("norm", norms), ("scale", scales), ("zero", zeros)):
+        if col is not None:
+            add(name, col.astype(jnp.float32))
+    if tomb is not None:
+        add("alive", ref.tombstone_mask(tomb, jnp.arange(n, dtype=jnp.int32)))
+    if lane > SIDE_LANES:
+        raise ValueError(f"{lane} sidecar lanes exceed {SIDE_LANES}")
+    side = jnp.concatenate(cols, axis=1)
+    return jnp.pad(side, ((0, 0), (0, SIDE_LANES - lane))), tuple(layout)
+
+
+def pack_query(q, layout):
+    """Reorder query columns to match codes packed by :func:`row_sidecar`:
+    the kernel unpacks byte b of every code word into one lane block, so
+    block b must meet query columns b, b+4, b+8, ..."""
+    if not any(f == "codes" for f, _, _ in layout):
+        return q
+    return jnp.concatenate([q[:, b::4] for b in range(4)], axis=1)
+
+
+def id_window(chunk: int) -> int:
+    """Words one aligned id-window DMA copies to cover ``chunk`` ids that
+    may start anywhere: whole ID_ALIGN tiles, one more than ``chunk``
+    needs."""
+    return -(-chunk // ID_ALIGN) * ID_ALIGN + ID_ALIGN
+
+
+def pad_id_table(ids, chunk: int):
+    """Pad a 1-D id table so that every aligned window of
+    :func:`id_window` words starting at or before ``len - chunk`` exists.
+    Returns (padded table, last valid window start + 1)."""
+    ids = jnp.asarray(ids, jnp.int32)
+    r_eff = max(ids.shape[0], chunk)
+    rp = -(-r_eff // ID_ALIGN) * ID_ALIGN + id_window(chunk)
+    return jnp.pad(ids, (0, rp - ids.shape[0])), r_eff - chunk + 1
+
+
+def dma_id_windows(ids_ref, idbuf, sem, starts, win):
+    """Copy, for each tile row t, the aligned window of ``ids_ref`` (a 1-D
+    i32 HBM table) that covers [starts[t], starts[t] + chunk) into SMEM at
+    ``t * win``.  Mosaic slices such a table only at whole ID_ALIGN tiles,
+    hence the aligned window.  Returns each row's SMEM offset of id 0."""
+    cps, offs = [], []
+    for t, cs in enumerate(starts):
+        a0 = pl.multiple_of((cs // ID_ALIGN) * ID_ALIGN, ID_ALIGN)
+        offs.append(t * win + cs - a0)
+        cps.append(pltpu.make_async_copy(
+            ids_ref.at[pl.ds(a0, win)], idbuf.at[pl.ds(t * win, win)], sem))
+    for cp in cps:
+        cp.start()
+    for cp in cps:
+        cp.wait()
+    return offs
+
+
+def gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk):
+    """Per-row gather DMAs (sidecar, and codes unless they ride in the
+    sidecar: ``ax_ref`` None) for every tile row, all in flight before the
+    first wait (the DMA engine pipelines them).  Issued from loops, not
+    unrolled, so the kernel's size does not grow with the tile."""
+    def copies(t, r, rid):
+        cps = [pltpu.make_async_copy(side_ref.at[pl.ds(rid, 1), :],
+                                     sbuf.at[t, pl.ds(r, 1), :], sem)]
+        if ax_ref is not None:
+            cps.append(pltpu.make_async_copy(ax_ref.at[pl.ds(rid, 1), :],
+                                             xbuf.at[t, pl.ds(r, 1), :], sem))
+        return cps
+
+    for t, off in enumerate(offs):
+        def start(r, carry, t=t, off=off):
+            for cp in copies(t, r, idbuf[off + r]):
+                cp.start()
+            return carry
+        jax.lax.fori_loop(0, chunk, start, 0)
+
+    def wait(i, carry):
+        # a wait consumes one copy's byte count; every row copy of a kind
+        # moves the same bytes, so any row's descriptor stands for it
+        for cp in copies(0, 0, 0):
+            cp.wait()
+        return carry
+    jax.lax.fori_loop(0, len(offs) * chunk, wait, 0)
+
+
+def filtered_distances(qv, lqv, xr, sv, layout, *, metric, dtype, dcols):
+    """Dequant + distance + label/tombstone filter of one gathered chunk,
+    all in registers: ``qv`` [T, Dp] f32 (:func:`pack_query` order),
+    ``lqv`` [T, W], ``xr`` [T, C, Dp] codes (None when they ride in the
+    sidecar), ``sv`` [T, C, SIDE_LANES] sidecar rows -> [T, C] f32, +inf
+    where filtered out."""
+    lanes = {f: (a, w) for f, a, w in layout}
+
+    def field(name):
+        a, w = lanes[name]
+        return sv[:, :, a:a + w]
+
+    def f32_field(name):
+        return jax.lax.bitcast_convert_type(field(name), jnp.float32)
+
+    if dtype == "int8":
+        # byte b of code word i is column 4i + b: unpack each byte into one
+        # lane block (u8 -> i32 -> f32 is exact; Mosaic casts no u8 -> f32)
+        words = field("codes")                              # [T, C, Dp/4]
+        nw = words.shape[2]
+        zero, scale = f32_field("zero"), f32_field("scale")
+        col = 4 * jax.lax.broadcasted_iota(jnp.int32, words.shape, 2)
+        ip = 0.0
+        for b in range(4):
+            code = jax.lax.shift_right_logical(words, 8 * b) & 0xFF
+            xb = zero + scale * code.astype(jnp.float32)
+            if dcols is not None:
+                # lane-pad code byte 0 dequantizes to the row zero-point,
+                # not 0 — mask the pad columns (DESIGN.md §3.9)
+                xb = jnp.where(col + b < dcols, xb, 0.0)
+            qb = jax.lax.slice_in_dim(qv, b * nw, (b + 1) * nw, axis=1)
+            ip = ip + jnp.sum(xb * qb[:, None, :], axis=-1)
+    else:
+        if dtype == "fp16":
+            xr = xr.astype(jnp.float32)
+        ip = jnp.sum(xr * qv[:, None, :], axis=-1)          # [T, C]
+    if metric == "ip":
+        d = -ip
+    else:
+        qn = jnp.sum(qv * qv, axis=1)
+        d = qn[:, None] - 2.0 * ip + f32_field("norm")[:, :, 0]
+    lx = field("labels")
+    keep = jnp.all((lqv[:, None, :] & lx) == lqv[:, None, :], axis=-1)
+    if "alive" in lanes:
+        keep = keep & (field("alive")[:, :, 0] != 0)
+    return jnp.where(keep, d, jnp.inf)
+
+
+def scan_operands(ax, alw, norms, scales, zeros, tomb, *, dtype):
+    """(codes operand or None, sidecar, layout) of one Pallas scan: the
+    int8 tier's codes ride in the sidecar, other tiers' rows are gathered
+    from ``ax`` itself."""
+    packed = dtype == "int8"
+    side, layout = row_sidecar(alw, norms, scales if packed else None,
+                               zeros if packed else None, tomb,
+                               codes=ax if packed else None)
+    return (None if packed else ax), side, layout
 
 
 def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
                        tomb, scales, zeros, *, kp, lmax, chunk, qtile,
                        metric, dtype, dcols, interpret):
     Q, Dp = q.shape
-    W = lq.shape[1]
     qtile = clamp_qtile(qtile, Q)
     nc = lmax // chunk
-    l2 = metric == "l2"
-    int8 = dtype == "int8"
-
-    # the id-window DMA reads a contiguous [chunk] slice of the row table;
-    # clamp the window start so it always stays in range (over-read lanes
-    # are masked by pos >= len), and pad the table so a window exists even
-    # when R < chunk (tiny selections)
-    rc = jnp.asarray(rows_concat, jnp.int32)
-    if rc.shape[0] < chunk:
-        rc = jnp.pad(rc, (0, chunk - rc.shape[0]))
-    rp = rc.shape[0]
-
-    operands = [rc, ax, alw]
-    if l2:
-        operands.append(axn.reshape(-1, 1).astype(jnp.float32))
-    if int8:
-        operands.append(scales.reshape(-1, 1).astype(jnp.float32))
-        operands.append(zeros.reshape(-1, 1).astype(jnp.float32))
-    if tomb is not None:
-        operands.append(_pack_tombstone_words(tomb))
+    win = id_window(chunk)
+    # window starts are clamped so they stay in range; over-read lanes are
+    # masked by pos >= len
+    rc, n_starts = pad_id_table(rows_concat, chunk)
+    ax, side, layout = scan_operands(
+        ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
+        dtype=dtype)
+    q = pack_query(q, layout)
 
     scratch = [
-        pltpu.SMEM((qtile, chunk), jnp.int32),           # id window
-        pltpu.VMEM((qtile, chunk, Dp), ax.dtype),        # gathered codes
-        pltpu.VMEM((qtile, chunk, W), jnp.int32),        # gathered labels
-        pltpu.VMEM((qtile, kp), jnp.float32),            # running vals
-        pltpu.VMEM((qtile, kp), jnp.int32),              # running pos
+        pltpu.SMEM((qtile * win,), jnp.int32),             # id windows
+        pltpu.VMEM((qtile, chunk, SIDE_LANES), jnp.int32),  # gathered sidecar
+        pltpu.VMEM((qtile, kp), jnp.float32),              # running vals
+        pltpu.VMEM((qtile, kp), jnp.int32),                # running pos
         pltpu.SemaphoreType.DMA,
     ]
-    if l2:
-        scratch.append(pltpu.VMEM((qtile, chunk, 1), jnp.float32))
-    if int8:
-        scratch.append(pltpu.VMEM((qtile, chunk, 1), jnp.float32))
-        scratch.append(pltpu.VMEM((qtile, chunk, 1), jnp.float32))
-    if tomb is not None:
-        scratch.append(pltpu.VMEM((qtile, chunk), jnp.int32))  # vector ids
-        scratch.append(pltpu.VMEM((qtile, chunk, 1), jnp.int32))
+    operands = [rc, side]
+    if ax is not None:
+        scratch.append(pltpu.VMEM((qtile, chunk, Dp), ax.dtype))  # codes
+        operands.append(ax)
 
-    def kernel(starts_sm, lens_sm, q_ref, lq_ref, rc_ref, ax_ref, alw_ref,
-               *rest):
-        it = iter(rest)
-        axn_ref = next(it) if l2 else None
-        s_ref = next(it) if int8 else None
-        z_ref = next(it) if int8 else None
-        tw_ref = next(it) if tomb is not None else None
-        vals_ref, pos_ref = next(it), next(it)
-        idbuf, xbuf, lwbuf, run_v, run_p, sem = (next(it) for _ in range(6))
-        nbuf = next(it) if l2 else None
-        sbuf = next(it) if int8 else None
-        zbuf = next(it) if int8 else None
-        idv = next(it) if tomb is not None else None
-        tbuf = next(it) if tomb is not None else None
-
+    def kernel(starts_sm, lens_sm, q_ref, lq_ref, rc_ref, side_ref, *rest):
+        if ax is None:
+            ax_ref, xbuf = None, None
+            vals_ref, pos_ref, idbuf, sbuf, run_v, run_p, sem = rest
+        else:
+            (ax_ref, vals_ref, pos_ref, idbuf, sbuf, run_v, run_p, sem,
+             xbuf) = rest
         ti = pl.program_id(0)
         ci = pl.program_id(1)
         c0 = ci * chunk
@@ -233,83 +379,20 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
             run_v[...] = jnp.full((qtile, kp), jnp.inf, jnp.float32)
             run_p[...] = jnp.full((qtile, kp), lmax, jnp.int32)
 
-        # -- phase 1: DMA each query's id window (contiguous CSR slice) --
-        id_cps = []
-        for t in range(qtile):
-            cs = jnp.clip(starts_sm[ti * qtile + t] + c0, 0, rp - chunk)
-            id_cps.append(pltpu.make_async_copy(
-                rc_ref.at[pl.ds(cs, chunk)], idbuf.at[t], sem))
-            if tomb is not None:
-                id_cps.append(pltpu.make_async_copy(
-                    rc_ref.at[pl.ds(cs, chunk)], idv.at[t], sem))
-        for cp in id_cps:
-            cp.start()
-        for cp in id_cps:
-            cp.wait()
-
-        # -- phase 2: per-row gather DMAs, all in flight before the first
-        # wait (the DMA engine pipelines them) --
-        row_cps = []
-        for t in range(qtile):
-            for r in range(chunk):
-                rid = idbuf[t, r]
-                row_cps.append(pltpu.make_async_copy(
-                    ax_ref.at[pl.ds(rid, 1), :],
-                    xbuf.at[t, pl.ds(r, 1), :], sem))
-                row_cps.append(pltpu.make_async_copy(
-                    alw_ref.at[pl.ds(rid, 1), :],
-                    lwbuf.at[t, pl.ds(r, 1), :], sem))
-                if l2:
-                    row_cps.append(pltpu.make_async_copy(
-                        axn_ref.at[pl.ds(rid, 1), :],
-                        nbuf.at[t, pl.ds(r, 1), :], sem))
-                if int8:
-                    row_cps.append(pltpu.make_async_copy(
-                        s_ref.at[pl.ds(rid, 1), :],
-                        sbuf.at[t, pl.ds(r, 1), :], sem))
-                    row_cps.append(pltpu.make_async_copy(
-                        z_ref.at[pl.ds(rid, 1), :],
-                        zbuf.at[t, pl.ds(r, 1), :], sem))
-                if tomb is not None:
-                    wi = jax.lax.shift_right_logical(rid, 5)
-                    row_cps.append(pltpu.make_async_copy(
-                        tw_ref.at[pl.ds(wi, 1), :],
-                        tbuf.at[t, pl.ds(r, 1), :], sem))
-        for cp in row_cps:
-            cp.start()
-        for cp in row_cps:
-            cp.wait()
-
-        # -- phase 3: dequant + distance + filter, all in registers --
-        qv = q_ref[...]                                     # [T, Dp]
-        xr = xbuf[...]
-        if dtype == "fp16":
-            xr = xr.astype(jnp.float32)
-        elif int8:
-            xr = zbuf[...] + sbuf[...] * xr.astype(jnp.float32)
-            if dcols is not None and dcols < Dp:
-                # lane-pad code byte 0 dequantizes to the row zero-point,
-                # not 0 — mask the pad lanes (DESIGN.md §3.9)
-                lane = jax.lax.broadcasted_iota(
-                    jnp.int32, (qtile, chunk, Dp), 2)
-                xr = jnp.where(lane < dcols, xr, 0.0)
-        ip = jnp.sum(xr * qv[:, None, :], axis=-1)          # [T, C]
-        if metric == "ip":
-            d = -ip
-        else:
-            qn = jnp.sum(qv * qv, axis=1)
-            d = qn[:, None] - 2.0 * ip + nbuf[...][:, :, 0]
-        lqv = lq_ref[...]
-        keep = jnp.all((lqv[:, None, :] & lwbuf[...]) == lqv[:, None, :],
-                       axis=-1)
-        if tomb is not None:
-            shift = idv[...] & 31
-            keep = keep & (
-                ((tbuf[...][:, :, 0] >> shift) & 1) == 0)
+        # -- phases 1-3: id windows (contiguous CSR slices), row gather,
+        # dequant + distance + filter --
+        offs = dma_id_windows(
+            rc_ref, idbuf, sem,
+            [jnp.clip(starts_sm[ti * qtile + t] + c0, 0, n_starts - 1)
+             for t in range(qtile)], win)
+        gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk)
+        d = filtered_distances(
+            q_ref[...], lq_ref[...], None if xbuf is None else xbuf[...],
+            sbuf[...], layout, metric=metric, dtype=dtype, dcols=dcols)
         lens_vec = jnp.stack(
             [lens_sm[ti * qtile + t] for t in range(qtile)])
         pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (qtile, chunk), 1)
-        d = jnp.where(keep & (pos < lens_vec[:, None]), d, jnp.inf)
+        d = jnp.where(pos < lens_vec[:, None], d, jnp.inf)
 
         # -- phase 4: merge the chunk into the VMEM-resident running
         # top-k.  Iterative first-min selection over [running | chunk]
@@ -352,8 +435,8 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
         num_scalar_prefetch=2,
         grid=(Q // qtile, nc),
         in_specs=[pl.BlockSpec((qtile, Dp), im),
-                  pl.BlockSpec((qtile, W), im)]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * len(operands),
+                  pl.BlockSpec((qtile, lq.shape[1]), im)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
         out_specs=[pl.BlockSpec((qtile, kp), im),
                    pl.BlockSpec((qtile, kp), im)],
         scratch_shapes=scratch,

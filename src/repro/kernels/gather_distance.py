@@ -1,7 +1,11 @@
-"""Pallas TPU kernel: scalar-prefetch neighbor gather + distance.
+"""Pallas TPU kernels: gathered-row distances.
 
-The graph-backend search loop repeatedly needs distances from the query to a
-*scattered* candidate set (the frontier's neighbor lists).  On TPU the
+``segmented_gather_distance_pallas`` is the unfused arena scan's gather
+(and the rerank stage's): the fused scan's id-window, row-gather and
+distance blocks (``fused_scan.py``) with the distances written out.
+
+``gather_distance_pallas``: the graph-backend search loop repeatedly needs
+distances from the query to a *scattered* candidate set (the frontier's neighbor lists).  On TPU the
 idiomatic pattern is scalar prefetch: the candidate id array arrives in SMEM
 ahead of the grid, and each grid step's BlockSpec ``index_map`` reads the id
 to DMA exactly that database row HBM→VMEM — a software-pipelined gather, no
@@ -18,11 +22,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU grid spec with scalar prefetch (works under interpret=True too)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .fused_scan import (SIDE_LANES, clamp_qtile, dma_id_windows,
+                         filtered_distances, gather_rows, id_window,
+                         pack_query, pad_id_table)
 
 INF = float("inf")
 
@@ -38,133 +42,90 @@ def _gather_distance_kernel(ids_ref, q_ref, x_ref, out_ref, *, metric: str):
     out_ref[0, 0] = d
 
 
-def _seg_gather_kernel(ids_ref, lens_ref, q_ref, lq_ref, x_ref, lx_ref,
-                       out_ref, *, metric: str):
-    """One grid step = one (query, candidate) pair: the candidate's arena
-    row was DMA'd HBM→VMEM by the BlockSpec index_map reading the
-    scalar-prefetched id table; fuse distance + label containment +
-    segment-validity into the [1, 1] output."""
-    qi = pl.program_id(0)
-    li = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32)          # [1, D]
-    xr = x_ref[...].astype(jnp.float32)         # [1, D]
-    ip = jnp.sum(q * xr)
-    if metric == "ip":
-        d = -ip
-    else:
-        d = jnp.sum((q - xr) ** 2)
-    lq = lq_ref[...]                            # [1, W]
-    lx = lx_ref[...]                            # [1, W]
-    ok = jnp.all((lq & lx) == lq)
-    valid = li < lens_ref[qi]
-    out_ref[0, 0] = jnp.where(ok & valid, d, INF)
-
-
-def _seg_gather_kernel_int8(ids_ref, lens_ref, q_ref, lq_ref, x_ref, lx_ref,
-                            s_ref, z_ref, out_ref, *, metric: str,
-                            dcols: int | None):
-    """Int8 variant of :func:`_seg_gather_kernel` (DESIGN.md §3.8): the
-    candidate row arrives as uint8 CODES — a quarter of the f32 row's DMA
-    bytes, and it stays uint8 in VMEM until this step's dequant.  The
-    per-row scale/zero-point ride the same index_map as the row ([1, 1]
-    blocks of the [N, 1] scale/zero columns), so dequant = zero + scale ·
-    code is one fused mul+add here, bitwise the eager upload-time value.
-    ``dcols`` masks lane-padding columns: a padded CODE byte of 0 would
-    dequantize to the row's zero-point, not 0, so lanes >= dcols are
-    forced back to 0 before the distance reduce."""
-    qi = pl.program_id(0)
-    li = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32)          # [1, D]
-    xr = z_ref[0, 0] + s_ref[0, 0] * x_ref[...].astype(jnp.float32)
-    if dcols is not None and dcols < xr.shape[1]:
-        lane = jax.lax.broadcasted_iota(jnp.int32, xr.shape, 1)
-        xr = jnp.where(lane < dcols, xr, 0.0)
-    ip = jnp.sum(q * xr)
-    if metric == "ip":
-        d = -ip
-    else:
-        d = jnp.sum((q - xr) ** 2)
-    lq = lq_ref[...]                            # [1, W]
-    lx = lx_ref[...]                            # [1, W]
-    ok = jnp.all((lq & lx) == lq)
-    valid = li < lens_ref[qi]
-    out_ref[0, 0] = jnp.where(ok & valid, d, INF)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "interpret", "dcols"))
-def segmented_gather_distance_pallas(q, lq, x, lxw, gids, lens, *,
-                                     metric: str = "l2",
-                                     interpret: bool = True,
-                                     scales=None, zeros=None,
+@functools.partial(jax.jit, static_argnames=("layout", "metric", "dtype",
+                                             "interpret", "dcols"))
+def segmented_gather_distance_pallas(q, lq, x, side, gids, lens, *,
+                                     layout: tuple, metric: str = "l2",
+                                     dtype: str = "f32",
+                                     interpret: bool,
                                      dcols: int | None = None):
     """Segmented arena gather + fused filtered distance (DESIGN.md §3).
 
-    ``q`` [Q, D] f32, ``lq`` [Q, W] i32; ``x`` [N, D] arena vectors;
-    ``lxw`` [N, W] arena label words; ``gids`` [Q, L] int32 arena row ids
-    per query (already resolved through the engine's CSR segment table,
-    clamped to range); ``lens`` [Q] int32 — positions >= len are masked to
-    +inf.  Returns [Q, L] f32 masked distances.
+    ``q`` [Q, Dp] f32, ``lq`` [Q, W] i32; ``x`` [N, Dp] arena rows (f32 or
+    f16), or None where the codes ride in the sidecar (int8 tier);
+    ``side``/``layout`` the per-row sidecar of ``fused_scan.row_sidecar``
+    (label words, the norm for l2, the int8 codes and scale/zero-point,
+    liveness); ``gids`` [Q, L] int32 arena row ids per query (already
+    resolved through the engine's CSR segment table, in range); ``lens``
+    [Q] int32 — positions >= len are masked to +inf, as are rows the label
+    filter or a tombstone drops.  Returns [Q, L] f32 distances.
 
-    TPU mapping: ``gids``/``lens`` are scalar-prefetched into SMEM ahead of
-    the grid; each (query, candidate) grid step's BlockSpec index_map reads
-    ``gids[qi, li]`` to DMA exactly that arena row HBM→VMEM — the same
-    software-pipelined gather idiom as :func:`gather_distance_pallas`,
-    extended with a second grid axis and the fused label filter.  Note the
-    id table lives in SMEM: callers bound Q·L (the ops wrapper chunks the
-    candidate span).
-
-    ``scales``/``zeros`` ([N] f32, int8 scan tier only, DESIGN.md §3.8):
-    ``x`` then holds uint8 codes which stay uint8 through the DMA and in
-    VMEM; the per-row scale/zero-point are gathered by the SAME
-    ``ids_ref[i, j]`` index_map (as [1, 1] blocks of their [N, 1] column
-    layout) and the dequant fuses into the distance step.
+    TPU mapping: the same building blocks as the fused scan — a grid over
+    (query tile, L chunk) whose step DMAs the tile's id windows from the
+    flattened ``gids`` into SMEM, gathers the referenced rows HBM→VMEM with
+    per-row async copies, and computes the filtered distances in registers
+    — but the chunk's distances go out to HBM instead of into a running
+    top-k.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas tpu grid specs unavailable")
     Q, L = gids.shape
-    D = q.shape[1]
-    W = lq.shape[1]
+    Dp = q.shape[1]
+    qtile = Q if Q <= 8 else clamp_qtile(8, Q)
+    chunk = 128 if L % 128 == 0 else L
+    win = id_window(chunk)
+    ids, _ = pad_id_table(gids.reshape(-1), chunk)
+    q = pack_query(q, layout)
+    scratch = [pltpu.SMEM((qtile * win,), jnp.int32),
+               pltpu.VMEM((qtile, chunk, SIDE_LANES), jnp.int32),
+               pltpu.SemaphoreType.DMA]
+    operands = [ids, side]
+    if x is not None:
+        scratch.append(pltpu.VMEM((qtile, chunk, Dp), x.dtype))
+        operands.append(x)
 
-    in_specs = [
-        pl.BlockSpec((1, D), lambda i, j, ids_ref, lens_ref: (i, 0)),
-        pl.BlockSpec((1, W), lambda i, j, ids_ref, lens_ref: (i, 0)),
-        pl.BlockSpec((1, D),
-                     lambda i, j, ids_ref, lens_ref: (ids_ref[i, j], 0)),
-        pl.BlockSpec((1, W),
-                     lambda i, j, ids_ref, lens_ref: (ids_ref[i, j], 0)),
-    ]
-    operands = [q, lq, x, lxw]
-    kernel = _seg_gather_kernel
-    if scales is not None:
-        in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda i, j, ids_ref, lens_ref: (ids_ref[i, j], 0)),
-            pl.BlockSpec((1, 1),
-                         lambda i, j, ids_ref, lens_ref: (ids_ref[i, j], 0)),
-        ]
-        operands += [scales.astype(jnp.float32)[:, None],
-                     zeros.astype(jnp.float32)[:, None]]
-        kernel = functools.partial(_seg_gather_kernel_int8, dcols=dcols)
+    def kernel(lens_sm, q_ref, lq_ref, ids_ref, side_ref, *rest):
+        if x is None:
+            x_ref, xbuf = None, None
+            out_ref, idbuf, sbuf, sem = rest
+        else:
+            x_ref, out_ref, idbuf, sbuf, sem, xbuf = rest
+        ti = pl.program_id(0)
+        c0 = pl.program_id(1) * chunk
+        offs = dma_id_windows(
+            ids_ref, idbuf, sem,
+            [(ti * qtile + t) * L + c0 for t in range(qtile)], win)
+        gather_rows(idbuf, offs, side_ref, sbuf, x_ref, xbuf, sem, chunk)
+        d = filtered_distances(
+            q_ref[...], lq_ref[...], None if xbuf is None else xbuf[...],
+            sbuf[...], layout, metric=metric, dtype=dtype, dcols=dcols)
+        lens_vec = jnp.stack(
+            [lens_sm[ti * qtile + t] for t in range(qtile)])
+        pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (qtile, chunk), 1)
+        out_ref[...] = jnp.where(pos < lens_vec[:, None], d, INF)
+
+    def im(i, j, lens_ref):
+        return (i, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Q, L),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, ids_ref, lens_ref: (i, j)),
+        num_scalar_prefetch=1,
+        grid=(Q // qtile, L // chunk),
+        in_specs=[pl.BlockSpec((qtile, Dp), im),
+                  pl.BlockSpec((qtile, lq.shape[1]), im)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
+        out_specs=pl.BlockSpec((qtile, chunk), lambda i, j, lens_ref: (i, j)),
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        functools.partial(kernel, metric=metric),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Q, L), jnp.float32),
         interpret=interpret,
-    )(gids.astype(jnp.int32), lens.astype(jnp.int32), *operands)
+    )(lens.astype(jnp.int32), q, lq, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "interpret"))
 def gather_distance_pallas(q_row, x, ids, *, metric: str = "l2",
                            interpret: bool = True):
     """[D], [N, D], [B] int32 -> [B] f32 distances; ids < 0 -> +inf."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas tpu grid specs unavailable")
     B = ids.shape[0]
     D = q_row.shape[0]
     clamped = jnp.maximum(ids, 0).astype(jnp.int32)

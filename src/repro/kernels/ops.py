@@ -18,7 +18,8 @@ from ..core.labels import masks_to_int32_words
 from ..obs import metrics as _metrics
 from . import ref
 from .filtered_topk import filtered_topk_pallas
-from .fused_scan import fused_segmented_scan, resolve_fused
+from .fused_scan import (fused_segmented_scan, resolve_fused, row_sidecar,
+                         scan_operands)
 from .gather_distance import (gather_distance_pallas,
                               segmented_gather_distance_pallas)
 from .masked_distance import LABEL_WORDS, masked_distance_pallas
@@ -185,6 +186,11 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     init = (jnp.full((Q, kp), jnp.inf, jnp.float32),
             jnp.full((Q, kp), lmax, jnp.int32))
 
+    if backend == "pallas" and not fused:
+        sx, side, layout = scan_operands(
+            ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
+            dtype=dtype)
+
     def body(carry, c0):  # unfused scan stage (fused=False)
         run_v, run_p = carry
         pos = c0 + jnp.arange(chunk, dtype=jnp.int32)          # [C]
@@ -192,15 +198,11 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         p = jnp.clip(starts[:, None] + pos[None, :], 0, max(R - 1, 0))
         gid = rows_concat[jnp.where(valid, p, 0)]              # [Q, C]
         if backend == "pallas":
+            # the kernel fuses label + tombstone filter and the lens mask
             d = segmented_gather_distance_pallas(
-                q, lq, ax, alw, gid, jnp.clip(lens - c0, 0, chunk),
-                metric=metric, interpret=interpret,
-                scales=scales, zeros=zeros, dcols=dcols)
-            if tomb is not None:
-                # the kernel fuses label filter + lens mask; the tombstone
-                # AND composes outside it — it can only add +inf lanes,
-                # never touch a surviving distance
-                d = jnp.where(ref.tombstone_mask(tomb, gid), d, jnp.inf)
+                q, lq, sx, side, gid, jnp.clip(lens - c0, 0, chunk),
+                layout=layout, metric=metric, dtype=dtype,
+                interpret=interpret, dcols=dcols)
         else:
             xg = ref.dequantize_rows(
                 ax[gid], dtype,
@@ -254,10 +256,12 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
             # shortlist rows already passed the label/tombstone filter;
             # position-sorted means the first sum(listed) lanes are the
             # live ones, which is exactly the kernel's lens mask
+            rside, rlayout = row_sidecar(
+                alw, rrn if metric == "l2" else None)
             d = segmented_gather_distance_pallas(
-                q, lq, rr, alw, sgid,
+                q, lq, rr, rside, sgid,
                 jnp.sum(listed, axis=1).astype(jnp.int32),
-                metric=metric, interpret=interpret)
+                layout=rlayout, metric=metric, interpret=interpret)
         else:
             xg = rr[sgid]                                      # [Q, kp, D]
             ip = jnp.sum(xg * q[:, None, :], axis=-1)

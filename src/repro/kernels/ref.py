@@ -17,10 +17,12 @@ FILTERED = jnp.float32(jnp.inf)
 
 
 def distances(q: jnp.ndarray, x: jnp.ndarray, metric: str = "l2") -> jnp.ndarray:
-    """[Q, D] x [N, D] -> [Q, N] distance matrix (f32 accumulate)."""
+    """[Q, D] x [N, D] -> [Q, N] distance matrix (f32 accumulate).  Full
+    f32 matmul precision: on a TPU the default runs a single bf16 pass,
+    and this is the exact reference."""
     q = q.astype(jnp.float32)
     x = x.astype(jnp.float32)
-    ip = q @ x.T
+    ip = jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return -ip
     if metric == "l2":

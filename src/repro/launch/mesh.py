@@ -1,37 +1,10 @@
-"""Production meshes.  Functions, not module-level constants — importing
-this module never touches jax device state (the dry-run sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* first jax
-init; everything else sees the single real CPU device).
-
-Topology (TPU v5e):
-    single-pod:  (data=16, model=16)          256 chips — one pod
-    multi-pod:   (pod=2, data=16, model=16)   512 chips — 2 pods over DCI
-
-'model' maps onto the pod's 2D ICI torus minor dimension (all-reduces for
-TP stay on fastest links); 'data' is the major dimension; 'pod' crosses
-the slower inter-pod links and carries only gradient all-reduce traffic
-(optionally int8-compressed, repro.optim.compressed_psum).
-"""
+"""Meshes.  Functions, not module-level constants — importing this module
+never touches jax device state."""
 from __future__ import annotations
 
 import jax
 
 from ..compat import make_mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices, have {len(devices)} — run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            f"(repro.launch.dryrun does this for you)")
-    return make_mesh(shape, axes, devices=devices)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

@@ -1,71 +1,47 @@
-"""Roofline analysis over the dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Fused-scan tile selection (DESIGN.md §3.9).
 
-Three terms per (arch × shape), single-pod mesh, TPU v5e constants:
+The fused segmented-scan kernel (kernels/fused_scan.py) streams candidate
+rows through VMEM in chunks of ``rows_per_chunk`` for ``queries_per_tile``
+queries at a time, keeping only the running (distance, position) top-k
+resident between chunks.  ``ops.segmented_topk`` asks
+:func:`fused_scan_tiles` for both whenever its caller sets no ``chunk``.
+The sizes fall out of a small capacity/intensity model:
 
-    compute    = HLO_FLOPs_per_device            / 197e12  FLOP/s (bf16 MXU)
-    memory     = HLO_bytes_accessed_per_device   / 819e9   B/s   (HBM)
-    collective = comm_bytes_per_device           / 50e9    B/s   (ICI/link)
+  * capacity — the chunk buffers, double-buffered, must fit the VMEM the
+    compiler allows (``VMEM_BYTES[device_kind]`` · ``VMEM_FRACTION``); the
+    lax/CPU fallback uses the same shape of bound against a last-level-
+    cache budget (``LLC_BYTES``) so the gathered [qtile, chunk, D] working
+    set stays cache-resident;
+  * intensity — the scan does ~2·D flops per ``scan_bytes_per_row`` bytes
+    of HBM traffic, far below the ridge point (peak FLOP/s over HBM
+    bandwidth), so the scan is memory-bound at every storage dtype and the
+    model's job is to maximize rows in flight per byte moved, never to
+    trade bytes for flops.
 
-All inputs come from the trip-count-aware HLO analysis (hlo_analysis.py —
-post-SPMD module, per-device semantics, ring factors, bf16-normalized
-collectives).  The bottleneck is the max term; the MFU bound is
-MODEL_FLOPS_per_device / (max_term · 197e12).
-
-MODEL_FLOPS = repro.arch.useful_flops: 6/2 · N_active · tokens plus the
-attention context term (PaLM accounting, window-capped local layers,
-enc/cross terms for whisper) and the SSD chunk term for Mamba2 layers.
-
-Usage:
-    PYTHONPATH=src python -m repro.launch.roofline [--mesh pod16x16]
-        [--json results/roofline.json] [--md]
+The model is *deterministic* per (D, span tier, dtype, Q-bucket, backend,
+device kind): warmup and serving resolve the same tiles, so tile selection
+adds no jit cache keys post-warmup.  ``autotune_fused_tiles`` is the
+measured escape hatch — it overrides the model for the rest of the
+process, cached per device kind, and must therefore run BEFORE warmup.
 """
 from __future__ import annotations
 
-import argparse
 import dataclasses
-import json
-from pathlib import Path
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per link (ICI)
-
-RESULTS = Path("results/dryrun")
-
-
-# ---------------------------------------------------------------------------
-# Fused-scan tile selection (DESIGN.md §3.9)
-#
-# The fused segmented-scan kernel (kernels/fused_scan.py) streams candidate
-# rows through VMEM in chunks of ``rows_per_chunk`` for ``queries_per_tile``
-# queries at a time, keeping only the running (distance, position) top-k
-# resident between chunks.  The tile sizes used to be hand constants
-# (``ops.SEG_CHUNK``); here they fall out of a small capacity/intensity
-# model instead:
-#
-#   * capacity — the chunk buffers (codes + label words + norms + int8
-#     sidecar + ids), double-buffered, must fit the VMEM budget
-#     (``VMEM_BYTES`` · ``VMEM_FRACTION``); the lax/CPU fallback uses the
-#     same shape of bound against a last-level-cache budget (``LLC_BYTES``)
-#     so the gathered [qtile, chunk, D] working set stays cache-resident;
-#   * intensity — the scan does ~2·D flops per ``scan_bytes_per_row`` bytes
-#     of HBM traffic, far below the ridge point (PEAK_FLOPS / HBM_BW), so
-#     the scan is memory-bound at every storage dtype and the model's job
-#     is to maximize rows in flight per byte moved, never to trade bytes
-#     for flops.
-#
-# The model is *deterministic* per (D, span tier, dtype, Q-bucket, backend):
-# warmup and serving resolve the same tiles, so tile selection adds no jit
-# cache keys post-warmup.  ``autotune_fused_tiles`` is the measured escape
-# hatch — it overrides the model for the rest of the process, cached per
-# device kind, and must therefore run BEFORE warmup (DESIGN.md §3.9).
-# ---------------------------------------------------------------------------
-
-VMEM_BYTES = 16 * 2**20     # per-core VMEM (TPU v4/v5 class)
+# VMEM the TPU compiler lets one kernel allocate, per device kind: the
+# limit a compile for a described chip reports when a kernel's scratch is
+# larger ("Allocation ... would exceed memory (size=134217728)", jax 0.9.0
+# with libtpu 0.0.34, compiled for v5e:2x2).  A Pallas launch on a kind
+# missing here is an error, not a default.
+VMEM_BYTES = {"TPU v5 lite": 128 * 2**20}
+# Pallas interpret mode (off-TPU) runs the kernel with the tiles of this
+# chip, so CPU tests exercise the schedule the chip compiles
+INTERPRET_DEVICE_KIND = "TPU v5 lite"
 VMEM_FRACTION = 0.5         # double-buffering + compiler headroom
 LLC_BYTES = 8 * 2**20       # lax fallback: cache-resident working set
-MAX_UNROLLED_ROWS = 1024    # pallas: row-DMA descriptors unrolled per step
+MAX_ROWS_IN_FLIGHT = 1024   # pallas: row DMAs issued per step before a wait
 LABEL_WORD_BYTES = 4
+SIDE_ROW_BYTES = 128 * 4    # one 128-lane i32 sidecar row per candidate
 
 _DTYPE_BYTES = {"f32": 4, "fp16": 2, "int8": 1}
 
@@ -120,7 +96,7 @@ def fused_scan_tiles(d: int, lmax: int, dtype: str, q_bucket: int, *,
     if dtype not in _DTYPE_BYTES:
         raise ValueError(f"unknown storage dtype {dtype!r}")
     if device_kind is None:
-        device_kind = _device_kind()
+        device_kind = _device_kind(backend)
     key = _tile_key(d, lmax, dtype, q_bucket, backend, device_kind)
     hit = _TILE_OVERRIDES.get(key)
     if hit is not None:
@@ -129,17 +105,22 @@ def fused_scan_tiles(d: int, lmax: int, dtype: str, q_bucket: int, *,
     intensity = (2.0 * d + 6.0) / row_bytes
     q_bucket = max(1, q_bucket)
     if backend == "pallas":
-        # VMEM-resident chunk buffers per query: codes at storage width,
-        # labels, norm, int8 sidecar, tombstone word, id — double-buffered.
-        vrow = (_DTYPE_BYTES[dtype] * d + label_words * LABEL_WORD_BYTES
-                + 4 + 4 + (8 if dtype == "int8" else 0) + 4)
+        if device_kind not in VMEM_BYTES:
+            raise ValueError(
+                f"no VMEM entry for device kind {device_kind!r}: compile the "
+                f"fused scan for it and add the limit to VMEM_BYTES")
+        # VMEM-resident chunk buffers per query: the 128-lane i32 sidecar
+        # row (labels, norm, int8 codes and scale/zero-point, liveness;
+        # kernels/fused_scan.py::row_sidecar), plus the codes row for the
+        # tiers gathered on their own — double-buffered.
+        vrow = SIDE_ROW_BYTES + (0 if dtype == "int8"
+                                 else _DTYPE_BYTES[dtype] * d)
         qt = min(_pow2_floor(q_bucket), 8)
-        budget = int(VMEM_BYTES * VMEM_FRACTION)
+        budget = int(VMEM_BYTES[device_kind] * VMEM_FRACTION)
         chunk = _pow2_floor(max(8, budget // (2 * qt * vrow)))
-        # the row gather is issued as unrolled async copies; cap the
-        # descriptor count per grid step (trace-size bound, not a memory
-        # bound)
-        chunk = min(chunk, max(8, MAX_UNROLLED_ROWS // qt))
+        # every row copy of a grid step is in flight before the first
+        # wait; cap how many (a bound on DMA queueing, not on memory)
+        chunk = min(chunk, max(8, MAX_ROWS_IN_FLIGHT // qt))
     else:
         # lax fallback: keep the gathered rows + the elementwise product
         # (~2 live [qtile, chunk, D] f32 arrays) inside the cache budget
@@ -163,7 +144,7 @@ def autotune_fused_tiles(d: int, lmax: int, dtype: str, q_bucket: int, *,
     next dispatch pays a retrace (the zero-new-traces invariant holds per
     tile choice, not across tile changes)."""
     if device_kind is None:
-        device_kind = _device_kind()
+        device_kind = _device_kind(backend)
     base = fused_scan_tiles(d, lmax, dtype, q_bucket, backend=backend,
                             label_words=label_words,
                             device_kind=device_kind)
@@ -186,112 +167,8 @@ def autotune_fused_tiles(d: int, lmax: int, dtype: str, q_bucket: int, *,
     return best
 
 
-def _device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:       # roofline CLI use without a jax runtime
-        return "unknown"
-
-
-def analyze_record(rec: dict, chips: int) -> dict | None:
-    if not rec.get("ok"):
-        return None
-    from repro import arch as A
-    arch = A.get_arch(rec["arch"])
-    shape = A.SHAPES[rec["shape"]]
-    model_flops = A.useful_flops(arch, shape)
-
-    t_compute = rec["per_device_flops"] / PEAK_FLOPS
-    t_memory = rec["bytes_accessed"] / HBM_BW
-    # bf16-normalized collective bytes (XLA-CPU promotes bf16 dots to f32
-    # and reorders converts across collectives; TPU keeps them bf16)
-    comm = rec.get("comm_bytes_per_device_tpu",
-                   rec["comm_bytes_per_device"])
-    t_comm = comm / LINK_BW
-    terms = {"compute_s": t_compute, "memory_s": t_memory,
-             "collective_s": t_comm}
-    bottleneck = max(terms, key=terms.get)
-    step_s = max(terms.values())
-    hlo_flops_global = rec["per_device_flops"] * chips
-    return {
-        "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
-        **{k: round(v, 6) for k, v in terms.items()},
-        "bottleneck": bottleneck.replace("_s", ""),
-        "model_flops": model_flops,
-        "hlo_flops_global": hlo_flops_global,
-        "useful_ratio": round(model_flops / hlo_flops_global, 4)
-        if hlo_flops_global else None,
-        "mfu_bound": round(model_flops / chips / PEAK_FLOPS / step_s, 4)
-        if step_s else None,
-        "peak_gib_per_device": round(
-            rec.get("peak_bytes_per_device", 0) / 2**30, 2),
-        "collectives": rec.get("collectives", {}),
-    }
-
-
-def load_all(mesh: str = "pod16x16") -> list[dict]:
-    chips = 512 if mesh == "pod2x16x16" else 256
-    out = []
-    for p in sorted(RESULTS.glob(f"*__{mesh}.json")):
-        rec = json.loads(p.read_text())
-        if rec.get("skipped"):
-            out.append({"arch": rec["arch"], "shape": rec["shape"],
-                        "mesh": mesh, "skipped": rec["skipped"]})
-            continue
-        r = analyze_record(rec, chips)
-        if r is None:
-            out.append({"arch": rec["arch"], "shape": rec["shape"],
-                        "mesh": mesh, "error": rec.get("error", "?")})
-        else:
-            out.append(r)
-    return out
-
-
-def to_markdown(rows: list[dict]) -> str:
-    hdr = ("| arch | shape | compute s | memory s | collective s | "
-           "bottleneck | MFLOPs ratio | MFU bound | peak GiB/dev |\n"
-           "|---|---|---|---|---|---|---|---|---|\n")
-    lines = []
-    for r in rows:
-        if "skipped" in r:
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | "
-                         f"skipped | — | — | — |")
-            continue
-        if "error" in r:
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | "
-                         f"ERROR | — | — | — |")
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['compute_s']:.4f} | "
-            f"{r['memory_s']:.4f} | {r['collective_s']:.4f} | "
-            f"**{r['bottleneck']}** | {r['useful_ratio']} | "
-            f"{r['mfu_bound']} | {r['peak_gib_per_device']} |")
-    return hdr + "\n".join(lines) + "\n"
-
-
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--mesh", default="pod16x16")
-    ap.add_argument("--json", default="results/roofline.json")
-    ap.add_argument("--md", action="store_true")
-    args = ap.parse_args()
-    rows = load_all(args.mesh)
-    Path(args.json).parent.mkdir(parents=True, exist_ok=True)
-    Path(args.json).write_text(json.dumps(rows, indent=1))
-    if args.md:
-        print(to_markdown(rows))
-    else:
-        for r in rows:
-            if "skipped" in r or "error" in r:
-                print(f"{r['arch']:24s} {r['shape']:12s} "
-                      f"{'SKIP' if 'skipped' in r else 'ERROR'}")
-            else:
-                print(f"{r['arch']:24s} {r['shape']:12s} "
-                      f"c={r['compute_s']:.4f}s m={r['memory_s']:.4f}s "
-                      f"x={r['collective_s']:.4f}s -> {r['bottleneck']:10s} "
-                      f"mfu<={r['mfu_bound']}")
-
-
-if __name__ == "__main__":
-    main()
+def _device_kind(backend: str) -> str:
+    import jax
+    if backend == "pallas" and jax.default_backend() != "tpu":
+        return INTERPRET_DEVICE_KIND
+    return jax.devices()[0].device_kind

@@ -20,6 +20,7 @@ from ..core.engine import LabelHybridEngine
 from ..data.pipeline import VectorLabelDataset
 from ..models.common import init_params
 from ..serve import BatchedDecoder, Request, RetrievalAugmentedEngine
+from . import compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=96)
     ap.add_argument("--no-rag", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = reduced_arch(args.arch)
     params = init_params(jax.random.PRNGKey(0), A.param_specs(spec))

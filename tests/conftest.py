@@ -11,28 +11,11 @@ Hypothesis is an optional test dependency (requirements-test.txt) — the
 property-based modules skip themselves via ``pytest.importorskip`` when it
 is absent, so this hook must degrade to a no-op rather than fail the whole
 collection.
-
-Also pins the LEGACY XLA:CPU runtime on jaxlib 0.4.x: the 0.4.3x "thunk"
-CPU runtime segfaults inside ``backend_compile`` once enough programs have
-accumulated in one process — a deterministic mid-suite crash in
-``test_streaming_engine.py`` (reproduced at the seed commit, single-core
-runner; the lone test passes, the 13th compile-heavy test in a fresh
-process dies).  The flag must be in the environment before the first jax
-backend initialization, which is why it is set at conftest import instead
-of in a fixture, and it is version-gated because newer jaxlib removed the
-legacy runtime along with the flag (an unknown XLA flag is a startup
-error — the CI latest-release leg must not see it).
 """
 
 from __future__ import annotations
 
 import os
-
-import jaxlib
-
-_flags = os.environ.get("XLA_FLAGS", "")
-if jaxlib.__version__.startswith("0.4.") and "thunk_runtime" not in _flags:
-    os.environ["XLA_FLAGS"] = f"{_flags} --xla_cpu_use_thunk_runtime=false".strip()
 
 try:
     from hypothesis import HealthCheck, settings

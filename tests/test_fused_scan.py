@@ -252,7 +252,18 @@ def test_tile_model_is_deterministic_and_divides():
     t = roofline.fused_scan_tiles(768, 8192, "f32", 64, backend="pallas")
     vmem = (2 * t.queries_per_tile * t.rows_per_chunk
             * roofline.scan_bytes_per_row(768, "f32"))
-    assert vmem <= roofline.VMEM_BYTES
+    assert vmem <= roofline.VMEM_BYTES[roofline.INTERPRET_DEVICE_KIND]
+
+
+def test_tile_model_rejects_unknown_device_kind():
+    """Pallas tiles come from the VMEM the compiler enforces for the chip;
+    a device kind with no entry is an error, never a guessed budget."""
+    with pytest.raises(ValueError, match="no VMEM entry"):
+        roofline.fused_scan_tiles(128, 4096, "f32", 64, backend="pallas",
+                                  device_kind="TPU v99")
+    # the lax fallback sizes from the host cache and needs no entry
+    roofline.fused_scan_tiles(128, 4096, "f32", 64, backend="ref",
+                              device_kind="TPU v99")
 
 
 def test_autotune_override_wins():

@@ -5,11 +5,11 @@ One kernel, two implementations sharing one chunking schedule:
 * :func:`_pallas_fused_scan` — a Pallas TPU kernel over a
   ``(Q // queries_per_tile, span // rows_per_chunk)`` grid.  Each grid
   step DMAs one chunk of the per-query candidate-id window from the CSR
-  row table (HBM → SMEM), gathers the referenced arena rows — codes,
-  label words, norms, int8 scale/zero sidecar, tombstone words — with
-  per-row async copies (HBM → VMEM, the scalar-prefetch gather idiom of
-  ``gather_distance.py`` turned inside the kernel), dequantizes
-  in-register with the ``dcols`` lane mask, computes multiply +
+  row table (HBM → SMEM), gathers for each id one 128-lane sidecar row —
+  label words, norm, liveness, and for the int8 tier the packed codes
+  and scale/zero-point (:func:`row_sidecar`) — plus the f32/fp16 code
+  row, with per-row async copies (HBM → VMEM), dequantizes in-register
+  with the ``dcols`` column mask, computes multiply +
   minor-axis-reduce distances, applies the packed-label + tombstone +
   segment-length filter, and merges the chunk into a running (distance,
   position) top-k held in VMEM scratch across chunks.  The ``[Q, span]``
@@ -248,11 +248,14 @@ def dma_id_windows(ids_ref, idbuf, sem, starts, win):
     return offs
 
 
-def gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk):
-    """Per-row gather DMAs (sidecar, and codes unless they ride in the
-    sidecar: ``ax_ref`` None) for every tile row, all in flight before the
-    first wait (the DMA engine pipelines them).  Issued from loops, not
-    unrolled, so the kernel's size does not grow with the tile."""
+def gather_rows(idbuf, offs, counts, side_ref, sbuf, ax_ref, xbuf, sem):
+    """Per-row gather DMAs for tile row t's first ``counts[t]`` ids
+    (sidecar, and codes unless they ride in the sidecar: ``ax_ref`` None),
+    all in flight before the first wait (the DMA engine pipelines them).
+    Rows past a query's segment are not fetched: their buffer lanes keep
+    stale values, which the caller's ``pos < len`` mask discards.  Issued
+    from loops, not unrolled, so the kernel's size does not grow with the
+    tile."""
     def copies(t, r, rid):
         cps = [pltpu.make_async_copy(side_ref.at[pl.ds(rid, 1), :],
                                      sbuf.at[t, pl.ds(r, 1), :], sem)]
@@ -261,12 +264,12 @@ def gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk):
                                              xbuf.at[t, pl.ds(r, 1), :], sem))
         return cps
 
-    for t, off in enumerate(offs):
+    for t, (off, n) in enumerate(zip(offs, counts)):
         def start(r, carry, t=t, off=off):
             for cp in copies(t, r, idbuf[off + r]):
                 cp.start()
             return carry
-        jax.lax.fori_loop(0, chunk, start, 0)
+        jax.lax.fori_loop(0, n, start, 0)
 
     def wait(i, carry):
         # a wait consumes one copy's byte count; every row copy of a kind
@@ -274,7 +277,7 @@ def gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk):
         for cp in copies(0, 0, 0):
             cp.wait()
         return carry
-    jax.lax.fori_loop(0, len(offs) * chunk, wait, 0)
+    jax.lax.fori_loop(0, sum(counts), wait, 0)
 
 
 def filtered_distances(qv, lqv, xr, sv, layout, *, metric, dtype, dcols):
@@ -385,12 +388,14 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
             rc_ref, idbuf, sem,
             [jnp.clip(starts_sm[ti * qtile + t] + c0, 0, n_starts - 1)
              for t in range(qtile)], win)
-        gather_rows(idbuf, offs, side_ref, sbuf, ax_ref, xbuf, sem, chunk)
+        lens_t = [lens_sm[ti * qtile + t] for t in range(qtile)]
+        gather_rows(idbuf, offs,
+                    [jnp.clip(n - c0, 0, chunk) for n in lens_t],
+                    side_ref, sbuf, ax_ref, xbuf, sem)
         d = filtered_distances(
             q_ref[...], lq_ref[...], None if xbuf is None else xbuf[...],
             sbuf[...], layout, metric=metric, dtype=dtype, dcols=dcols)
-        lens_vec = jnp.stack(
-            [lens_sm[ti * qtile + t] for t in range(qtile)])
+        lens_vec = jnp.stack(lens_t)
         pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (qtile, chunk), 1)
         d = jnp.where(pos < lens_vec[:, None], d, jnp.inf)
 

@@ -93,12 +93,14 @@ def segmented_gather_distance_pallas(q, lq, x, side, gids, lens, *,
         offs = dma_id_windows(
             ids_ref, idbuf, sem,
             [(ti * qtile + t) * L + c0 for t in range(qtile)], win)
-        gather_rows(idbuf, offs, side_ref, sbuf, x_ref, xbuf, sem, chunk)
+        lens_t = [lens_sm[ti * qtile + t] for t in range(qtile)]
+        gather_rows(idbuf, offs,
+                    [jnp.clip(n - c0, 0, chunk) for n in lens_t],
+                    side_ref, sbuf, x_ref, xbuf, sem)
         d = filtered_distances(
             q_ref[...], lq_ref[...], None if xbuf is None else xbuf[...],
             sbuf[...], layout, metric=metric, dtype=dtype, dcols=dcols)
-        lens_vec = jnp.stack(
-            [lens_sm[ti * qtile + t] for t in range(qtile)])
+        lens_vec = jnp.stack(lens_t)
         pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (qtile, chunk), 1)
         out_ref[...] = jnp.where(pos < lens_vec[:, None], d, INF)
 

@@ -163,7 +163,7 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     ``"f32"`` (the default) runs byte-for-byte today's program;
     ``"fp16"``/``"int8"`` scan dequantized codes (int8 gathers the per-row
     ``scales``/``zeros`` alongside, and on the pallas backend the codes
-    stay uint8 in VMEM).  With a rerank tier (``rr``/``rrn``, the exact
+    travel packed four to a 32-bit word, one byte per code).  With a rerank tier (``rr``/``rrn``, the exact
     f32 rows + norms) the scan instead keeps a k' = ``kprime`` shortlist
     which a second in-program stage reranks exactly: the shortlist is
     re-sorted by segment position (so ``lax.top_k``'s lower-index
@@ -317,7 +317,7 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
     routed group whose candidate segment fits in ``lmax`` — the batched
     executor's arena hot path.  ``backend="ref"`` gathers with ``jnp.take``
     (XLA-fused, the CPU/CI configuration); ``backend="pallas"`` uses the
-    scalar-prefetch DMA gather kernel (compiled on TPU).
+    per-row DMA gather kernels (compiled on TPU, interpreted elsewhere).
 
     Returns (vals [Q, k] asc, pos [Q, k] int32 positions RELATIVE to each
     query's segment (pos == ``lmax`` ⇒ empty slot), gid [Q, k] int32

@@ -11,11 +11,27 @@ Hypothesis is an optional test dependency (requirements-test.txt) — the
 property-based modules skip themselves via ``pytest.importorskip`` when it
 is absent, so this hook must degrade to a no-op rather than fail the whole
 collection.
+
+Frees compiled programs after each test module (``_release_compiled_programs``).
 """
 
 from __future__ import annotations
 
 import os
+
+import pytest
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Free every compiled program when a test module is done.  On the CPU
+    backend each program holds about 80 memory maps; a test worker that
+    keeps the programs of every module it ran can pass the kernel's limit
+    on maps (65530) and die with a segfault inside XLA's compiler."""
+    yield
+    import jax
+    jax.clear_caches()
+
 
 try:
     from hypothesis import HealthCheck, settings

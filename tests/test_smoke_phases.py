@@ -11,24 +11,13 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import numpy as np
-import pytest
 
 from repro.launch import smoke
 
 TINY = smoke.SmokeSizes(n=1500, dim=16, n_labels=8, n_queries=16, k=10,
                         stream_rows=64, n_requests=6)
 REPO = Path(__file__).resolve().parents[1]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def release_compiled_programs():
-    """The phases compile a few hundred programs, which on the CPU backend
-    hold thousands of memory maps; free them for the tests that follow in
-    this process, which share its limit on maps."""
-    yield
-    jax.clear_caches()
 
 
 def test_smoke_phases_tiny():

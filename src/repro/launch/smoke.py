@@ -35,6 +35,11 @@ def check(ok: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
+K = 10                  # neighbours per query
+C = 0.2                 # EIS elastic-factor bound (the paper's ELI-0.2)
+MIN_INT8_RECALL = 0.99  # gate for the int8+rerank tier
+
+
 @dataclasses.dataclass(frozen=True)
 class SmokeSizes:
     """The workload: the paper's base size (``configs/eli_paper.py``) with
@@ -43,11 +48,8 @@ class SmokeSizes:
     dim: int = 128
     n_labels: int = 12
     n_queries: int = 256
-    k: int = 10
-    c: float = 0.2
     stream_rows: int = 10_000
     n_requests: int = 32
-    min_int8_recall: float = 0.99
 
 
 def make_data(sizes: SmokeSizes, seed: int):
@@ -137,6 +139,17 @@ def exact_mismatches(ref: HostReference, qv, qls, got_ids: np.ndarray,
     return bad, worst
 
 
+def check_exact(tag, ref: HostReference, qv, qls, got_ids, sentinel,
+                want_ids, want_d, log) -> None:
+    """Log and require :func:`exact_mismatches` to find nothing."""
+    bad, worst = exact_mismatches(ref, qv, qls, got_ids, sentinel, want_ids,
+                                  want_d)
+    log(f"[{tag}] exact vs host float64 on {len(qls) - len(bad)}/"
+        f"{len(qls)} queries (largest distance gap {worst:.3g})")
+    check(not bad, f"{tag}: {len(bad)} queries differ from the reference, "
+                   f"first {bad[:5]}")
+
+
 def recall(got_ids: np.ndarray, want_ids: np.ndarray) -> float:
     hit = total = 0
     for g, w in zip(got_ids, want_ids):
@@ -180,7 +193,7 @@ def run_phases(sizes: SmokeSizes, seed: int,
     from ..serve import (BatchedDecoder, Request, RetrievalAugmentedEngine,
                          ServeStatus, ServingRuntime)
 
-    k, out = sizes.k, {}
+    k, out = K, {}
     (x, ls, qv, qls), dt = _timed(lambda: make_data(sizes, seed))
     log(f"[data] {x.shape[0]:,} x {x.shape[1]} f32 rows, "
         f"{sizes.n_labels} labels, {len(qls)} filtered queries, "
@@ -190,16 +203,11 @@ def run_phases(sizes: SmokeSizes, seed: int,
     log(f"[reference] host float64 brute force: {dt + dt2:.1f}s")
 
     def exact(tag, eng_ids, sentinel, r=ref, wi=want_i, wd=want_d):
-        bad, worst = exact_mismatches(r, qv, qls, eng_ids, sentinel, wi, wd)
-        log(f"[{tag}] exact vs host float64 on "
-            f"{len(qls) - len(bad)}/{len(qls)} queries "
-            f"(largest distance gap {worst:.3g})")
-        check(not bad, f"{tag}: {len(bad)} queries differ from the "
-                       f"reference, first {bad[:5]}")
+        check_exact(tag, r, qv, qls, eng_ids, sentinel, wi, wd, log)
 
     # -- a: XLA segmented scan --
     eng, dt = _timed(lambda: LabelHybridEngine.build(
-        x, ls, mode="eis", c=sizes.c, backend="flat"))
+        x, ls, mode="eis", c=C, backend="flat"))
     st = eng.stats()
     log(f"[a] build: {dt:.1f}s, {st.n_selected} indexes, achieved "
         f"c={st.achieved_c:.3f}, arena tiers {eng.arena.tier_nbytes}")
@@ -229,9 +237,8 @@ def run_phases(sizes: SmokeSizes, seed: int,
         else:
             rec = recall(i_b, want_i)
             log(f"[b {storage}] recall@{k} vs host float64: {rec:.4f}")
-            check(rec >= sizes.min_int8_recall,
-                  f"int8+rerank recall@{k} {rec:.4f} < "
-                  f"{sizes.min_int8_recall}")
+            check(rec >= MIN_INT8_RECALL,
+                  f"int8+rerank recall@{k} {rec:.4f} < {MIN_INT8_RECALL}")
         out[f"b_{storage}"] = {"first_s": t_first, "warm_s": t_warm,
                                "ids_equal_xla": same}
         del eb
@@ -323,7 +330,7 @@ def run_sharded(sizes: SmokeSizes, seed: int,
 
     from ..core.engine import LabelHybridEngine
 
-    k = sizes.k
+    k = K
     n_dev = len(jax.devices())
     (x, ls, qv, qls), dt = _timed(lambda: make_data(sizes, seed))
     log(f"[data] {x.shape[0]:,} x {x.shape[1]} f32 rows, "
@@ -333,16 +340,11 @@ def run_sharded(sizes: SmokeSizes, seed: int,
     want_i, want_d = ref.topk(qv, qls, k)
 
     def exact(tag, eng_ids, sentinel):
-        bad, worst = exact_mismatches(ref, qv, qls, eng_ids, sentinel,
-                                      want_i, want_d)
-        log(f"[{tag}] exact vs host float64 on "
-            f"{len(qls) - len(bad)}/{len(qls)} queries "
-            f"(largest distance gap {worst:.3g})")
-        check(not bad, f"{tag}: {len(bad)} queries differ from the "
-                       f"reference, first {bad[:5]}")
+        check_exact(tag, ref, qv, qls, eng_ids, sentinel, want_i, want_d,
+                    log)
 
     flat, dt = _timed(lambda: LabelHybridEngine.build(
-        x, ls, mode="eis", c=sizes.c, backend="flat"))
+        x, ls, mode="eis", c=C, backend="flat"))
     (_, i_flat), t_flat = _timed(lambda: flat.search_batched(qv, qls, k))
     log(f"[flat] build {dt:.1f}s; executor: {_executor(flat)} on "
         f"{flat.arena.vectors.devices()}; search: {t_flat:.2f}s")

@@ -15,7 +15,7 @@ import numpy as np
 
 from repro.launch import smoke
 
-TINY = smoke.SmokeSizes(n=1500, dim=16, n_labels=8, n_queries=16, k=10,
+TINY = smoke.SmokeSizes(n=1500, dim=16, n_labels=8, n_queries=16,
                         stream_rows=64, n_requests=6)
 REPO = Path(__file__).resolve().parents[1]
 
@@ -33,7 +33,7 @@ def test_smoke_phases_tiny():
 
 def test_sharded_smoke_tiny():
     out = smoke.run_sharded(TINY, seed=0, log=lambda line: None)
-    assert out["ids_equal_flat"] == TINY.n_queries * TINY.k
+    assert out["ids_equal_flat"] == TINY.n_queries * smoke.K
 
 
 def test_reference_catches_a_wrong_result():
@@ -41,7 +41,7 @@ def test_reference_catches_a_wrong_result():
     farther passing row is caught."""
     x, ls, qv, qls = smoke.make_data(TINY, seed=1)
     ref = smoke.HostReference(x, ls, TINY.n_labels)
-    ids, dist = ref.topk(qv, qls, TINY.k)
+    ids, dist = ref.topk(qv, qls, smoke.K)
     assert smoke.exact_mismatches(ref, qv, qls, ids, TINY.n, ids,
                                   dist)[0] == []
     worse = ids.copy()

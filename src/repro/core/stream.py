@@ -677,7 +677,7 @@ class StreamingEngine:
         the tombstone-fused base program per (k, Q-bucket, span tier), the
         delta scan per (k, Q-bucket, current capacity tier), and the merge
         per (k, Q-bucket) — so the first post-insert batch pays no retrace
-        (measured subprocess-isolated in exp10, the exp9 pattern).
+        (measured with compile caches cleared in exp10, the exp9 pattern).
         Private-storage backends fold pending inserts and delegate to the
         base warmup, tracing each index's tombstone-masked variant too
         when lazy deletes are active (first post-delete batch: no

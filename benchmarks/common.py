@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import (LabelWorkloadConfig, brute_force_filtered,
                         generate_label_sets, generate_query_label_sets,
                         recall_at_k)
+from repro.launch import compile_cache
 from repro.obs import metrics as obs_metrics
 
 
@@ -25,15 +26,12 @@ from repro.obs import metrics as obs_metrics
 def cold_compiles():
     """Every program the block runs is compiled anew: the in-memory jit
     caches are cleared and the persistent compilation cache is off for the
-    block.  Cold-start measurements run in process this way — a child
-    process could not reach the accelerator the parent holds."""
-    was = jax.config.jax_enable_compilation_cache
+    block (``compile_cache.off``).  Cold-start measurements run in process
+    this way — a child process could not reach the accelerator the parent
+    holds."""
     jax.clear_caches()
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
+    with compile_cache.off():
         yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
 
 
 def latency_percentiles(lat_s: list[float]) -> dict:

@@ -38,7 +38,8 @@ def main() -> int:
               f"refusing to run on the CPU", file=sys.stderr)
         return 1
     want = 4 if args.four_chips else 1
-    if len(devices) < want:
+    if len(devices) < want or (args.four_chips and len(devices) != want):
+        # the sharded backend spreads over every local device
         print(f"chip_smoke: needs {want} TPU devices, found {len(devices)}",
               file=sys.stderr)
         return 1
@@ -50,9 +51,9 @@ def main() -> int:
     def log(line: str) -> None:
         print(line, flush=True)
 
-    log(f"[setup] {len(devices)} x {devices[0].device_kind}; compile cache "
-        f"{compile_cache.enable()}; Pallas interpret mode "
-        f"{ops.default_interpret()}")
+    log(f"[setup] {len(devices)} x {devices[0].device_kind} visible, {want} "
+        f"used; compile cache {compile_cache.enable()}; Pallas interpret "
+        f"mode {ops.default_interpret()}")
     if ops.default_interpret():
         print("chip_smoke: Pallas would run in interpret mode on the chip",
               file=sys.stderr)
@@ -69,7 +70,7 @@ def main() -> int:
         f"device {peaks}")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
-        "count": len(devices)}}))
+        "count": want}}))
     return 0
 
 

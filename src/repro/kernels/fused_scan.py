@@ -221,13 +221,13 @@ def id_window(chunk: int) -> int:
 
 
 def pad_id_table(ids, chunk: int):
-    """Pad a 1-D id table so that every aligned window of
-    :func:`id_window` words starting at or before ``len - chunk`` exists.
-    Returns (padded table, last valid window start + 1)."""
+    """Pad a 1-D id table so that the aligned window of :func:`id_window`
+    words that covers ``chunk`` ids from any start up to ``len`` exists.
+    Returns (padded table, largest such start)."""
     ids = jnp.asarray(ids, jnp.int32)
     r_eff = max(ids.shape[0], chunk)
     rp = -(-r_eff // ID_ALIGN) * ID_ALIGN + id_window(chunk)
-    return jnp.pad(ids, (0, rp - ids.shape[0])), r_eff - chunk + 1
+    return jnp.pad(ids, (0, rp - ids.shape[0])), r_eff
 
 
 def dma_id_windows(ids_ref, idbuf, sem, starts, win):
@@ -346,9 +346,10 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
     qtile = clamp_qtile(qtile, Q)
     nc = lmax // chunk
     win = id_window(chunk)
-    # window starts are clamped so they stay in range; over-read lanes are
-    # masked by pos >= len
-    rc, n_starts = pad_id_table(rows_concat, chunk)
+    # a chunk that holds rows of its segment starts below the table's end,
+    # so its window is read where it lies; starts past the end (chunks past
+    # a segment, which gather no rows) are clamped to stay in range
+    rc, max_start = pad_id_table(rows_concat, chunk)
     ax, side, layout = scan_operands(
         ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
         dtype=dtype)
@@ -386,7 +387,7 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
         # dequant + distance + filter --
         offs = dma_id_windows(
             rc_ref, idbuf, sem,
-            [jnp.clip(starts_sm[ti * qtile + t] + c0, 0, n_starts - 1)
+            [jnp.clip(starts_sm[ti * qtile + t] + c0, 0, max_start)
              for t in range(qtile)], win)
         lens_t = [lens_sm[ti * qtile + t] for t in range(qtile)]
         gather_rows(idbuf, offs,

@@ -7,9 +7,13 @@ library code never does, and neither do the tests.  Where
 nothing else is set.  Otherwise the cache goes to ``.jax_cache/`` at the
 root of the checkout: a fixed path, because the path is part of the cache's
 key and a directory that moves never hits.
+
+:func:`off` keeps the persistent cache out of a block, for measurements of
+cold compiles and for compiles for a chip that is not there.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from pathlib import Path
 
@@ -25,3 +29,23 @@ def enable() -> str:
     path = str(CHECKOUT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+@contextlib.contextmanager
+def off():
+    """Nothing compiled inside the block is read from or written to the
+    persistent cache.  JAX decides whether the cache is in use at the
+    process's first compile and keeps that answer, so turning the flag off
+    changes nothing by itself: the cache's state is reset on entry, so that
+    the block's first compile sees the flag off, and on exit, so that the
+    next compile sees it restored."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    compilation_cache.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

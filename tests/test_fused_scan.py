@@ -55,13 +55,15 @@ SPECS = ("f32", "fp16", "int8", "fp16+rerank", "int8+rerank")
 
 
 def make_case(spec: str, *, N=400, D=24, Q=16, W=2, lmax=32, seed=0,
-              integer=True, tomb=True):
+              integer=True, tomb=True, tail=False):
     """Build raw segmented_topk operands for ``spec``.
 
     ``integer=True`` draws small-integer vectors: every distance is exact
     in f32, ties abound, and bitwise assertions pin the (distance,
     position) ORDER of the merge rather than accidentally passing on
-    distinct values."""
+    distinct values.  ``tail=True`` makes the first three segments end at
+    the row table's end, with lengths no power of two divides: their last
+    chunk is partial and its id window overhangs the table."""
     rng = np.random.default_rng(seed)
     if integer:
         xf = rng.integers(-4, 5, (N, D)).astype(np.float32)
@@ -75,6 +77,9 @@ def make_case(spec: str, *, N=400, D=24, Q=16, W=2, lmax=32, seed=0,
     rows = rng.integers(0, N, (Q * lmax,)).astype(np.int32)
     starts = (np.arange(Q) * lmax).astype(np.int32)
     lens = rng.integers(0, lmax + 1, (Q,)).astype(np.int32)
+    if tail:
+        lens[:3] = (lmax - 3, 5, lmax - 1)
+        starts[:3] = Q * lmax - lens[:3]
     tb = (rng.integers(0, 256, ((N + 7) // 8,)).astype(np.uint8)
           if tomb else None)
 
@@ -199,6 +204,17 @@ def test_pallas_fused_bitwise_vs_oracle(spec):
 def test_pallas_fused_no_tombstones():
     check_fused_parity("f32", k=4, chunk=8, qtile=2, backend="pallas",
                        N=200, Q=4, lmax=16, tomb=False)
+
+
+@pytest.mark.parametrize("spec", ("f32", "int8+rerank"))
+def test_pallas_fused_segments_ending_at_table_end(spec):
+    """A segment that ends at the row table's end with a partial last
+    chunk reads its ids from where they lie, not from a window shifted
+    back to fit inside the table."""
+    check_fused_parity(spec, k=4, chunk=8, qtile=2, backend="pallas",
+                       N=200, Q=4, lmax=16, tail=True)
+    check_fused_parity(spec, k=4, chunk=8, qtile=2, backend="ref",
+                       N=200, Q=4, lmax=16, tail=True)
 
 
 # ---------------------------------------------------------------------------

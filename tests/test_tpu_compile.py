@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.launch import roofline
+from repro.launch import compile_cache, roofline
 
 N, D, W, K = 1_000_000, 128, 4, 10
 R = 3_000_000          # CSR row-table entries (Σ|I| of a selection)
@@ -39,10 +39,8 @@ def one_chip():
     assert topo.devices[0].device_kind == KIND
     # a compile for a described chip cannot be read back from the
     # persistent cache without the chip: keep the cache out of it
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
+    with compile_cache.off():
+        yield SingleDeviceSharding(topo.devices[0])
 
 
 def compile_scan(sharding, *, q, lmax, dtype="f32", tomb=False,

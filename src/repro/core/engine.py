@@ -50,9 +50,10 @@ from .sis import SISResult, sis
 
 # Search-path telemetry (DESIGN.md §6.3).  Everything here is host-side
 # bookkeeping gated on the obs enabled flags: with telemetry off the whole
-# apparatus is one boolean check per batch, and with it on nothing touches
-# jax — search bits and the jit caches are untouched either way (pinned by
-# tests/test_obs_invariants.py).
+# apparatus is one boolean check per site, and with it on nothing touches
+# the device (a span opens a host-side profiler annotation while tracing
+# is on) — search bits and the jit caches are untouched either way
+# (pinned by tests/test_obs_invariants.py).
 _M_QUERIES = _metrics.counter(
     "eli_search_queries_total", "queries served by the batched executor",
     ("backend",),
@@ -67,7 +68,7 @@ _M_LAT = _metrics.histogram(
 )
 _M_STAGE = _metrics.histogram(
     "eli_search_stage_seconds",
-    "search_batched phase split: route vs dispatch+collect",
+    "search_batched host stages: route, pack, launch, sync",
     ("stage",),
 )
 _M_EF = _metrics.histogram(
@@ -110,14 +111,36 @@ _M_ACHIEVED = _metrics.gauge(
 )
 
 
-def record_search_telemetry(engine, routed, qmasks, k, n_queries, *,
-                            t_start, t_route, seg_before=None,
-                            tier_bucket=None, min_bucket=1,
+class StageClock:
+    """Host seconds of one search call's stages, for
+    ``eli_search_stage_seconds``: :meth:`lap` charges the time since the
+    previous lap (or the clock's start) to a stage.  Stamps are taken only
+    while telemetry is on, at the points where the call's program spans
+    (``search.route``, ``search.pack``, ``search.launch``,
+    ``search.sync``) open and close."""
+
+    __slots__ = ("on", "t0", "t", "seconds")
+
+    def __init__(self, on: bool):
+        self.on = on
+        self.t0 = self.t = time.perf_counter() if on else 0.0
+        self.seconds: dict[str, float] = {}
+
+    def lap(self, stage: str) -> None:
+        if self.on:
+            t = time.perf_counter()
+            self.seconds[stage] = self.seconds.get(stage, 0.0) + t - self.t
+            self.t = t
+
+
+def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
+                            seg_before=None, tier_bucket=None, min_bucket=1,
                             tomb_density=None, backend=None):
     """Per-batch query-path accounting — the single home of the metrics
     + query-card emission shared by ``LabelHybridEngine.search_batched``
     and the streaming executor (``core.stream``).  Called only when
-    telemetry is enabled; pure host work."""
+    telemetry is enabled; pure host work.  ``clock`` is the call's
+    :class:`StageClock`."""
     t_end = time.perf_counter()
     backend = backend or engine.backend
     arena = getattr(engine, "arena", None)
@@ -131,9 +154,9 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, *,
         _M_QUERIES.labels(backend).inc(n_queries)
         _M_BATCHES.labels(backend).inc()
         _M_LAT.labels(backend, pow2_bucket(n_queries, min_bucket),
-                      dtype).observe(t_end - t_start)
-        _M_STAGE.labels("route").observe(t_route - t_start)
-        _M_STAGE.labels("dispatch").observe(t_end - t_route)
+                      dtype).observe(t_end - clock.t0)
+        for stage, seconds in clock.seconds.items():
+            _M_STAGE.labels(stage).observe(seconds)
         if seg_delta > 0:
             _M_RECOMPILE.inc()
         if bound is not None:
@@ -178,12 +201,6 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, *,
                 q_bucket=q_bucket, dtype=dtype, shortlist=shortlist,
                 tombstone_density=tomb_density,
                 recompiled=seg_delta > 0, backend=backend))
-    if tracing:
-        tr = _trace.get_tracer()
-        tr.complete("search.route", t_start, t_route, Q=n_queries,
-                    backend=backend)
-        tr.complete("search.dispatch", t_route, t_end, k=k, backend=backend,
-                    groups=len(groups))
 
 
 def publish_engine_gauges(st) -> None:
@@ -580,21 +597,22 @@ class LabelHybridEngine:
         executor there.
         """
         telem = _metrics.enabled() or _trace.enabled()
-        t_start = time.perf_counter() if telem else 0.0
-        queries = np.asarray(queries, dtype=np.float32)
-        Q = queries.shape[0]
-        # sentinel/dtype contract: ids int32, empty slot == n (asserted
-        # here so third-party callers hit it before any device work)
-        n = check_global_id_contract(len(self.label_sets))
-        out_d = np.full((Q, k), np.inf, dtype=np.float32)
-        out_i = np.full((Q, k), n, dtype=np.int32)
-        if Q == 0:
-            return out_d, out_i
-
-        qmasks = encode_many(query_label_sets)
-        qwords = masks_to_int32_words(qmasks)
-        routed = self.route_many(query_label_sets, qmasks)
-        t_route = time.perf_counter() if telem else 0.0
+        clock = StageClock(telem)
+        with _trace.span("search.route", Q=len(queries),
+                         backend=self.backend):
+            queries = np.asarray(queries, dtype=np.float32)
+            Q = queries.shape[0]
+            # sentinel/dtype contract: ids int32, empty slot == n (asserted
+            # here so third-party callers hit it before any device work)
+            n = check_global_id_contract(len(self.label_sets))
+            out_d = np.full((Q, k), np.inf, dtype=np.float32)
+            out_i = np.full((Q, k), n, dtype=np.int32)
+            if Q == 0:
+                return out_d, out_i
+            qmasks = encode_many(query_label_sets)
+            qwords = masks_to_int32_words(qmasks)
+            routed = self.route_many(query_label_sets, qmasks)
+        clock.lap("route")
         pend: list[tuple[list[int], object, object, int]] = []
 
         if self._arena_native and self.arena is not None:
@@ -613,26 +631,34 @@ class LabelHybridEngine:
             for qids, qp, lp, starts, lens, lmax, g in \
                     self.arena_tier_batches(queries, qwords, routed,
                                             min_bucket):
+                clock.lap("pack")
                 if telem:
                     tier_bucket[lmax] = qp.shape[0]
-                vals, _, gi = _kernel_ops.segmented_topk(
-                    qp, lp, self.arena.vectors, self.arena.label_words,
-                    self.arena.norms, self._rows_concat_dev, starts, lens,
-                    k=k, lmax=lmax, metric=self.metric,
-                    backend=self._seg_backend, fused=self._seg_fused,
-                    **self.arena.tier_kwargs())
+                with _trace.span("search.launch", lmax=lmax,
+                                 bucket=qp.shape[0]):
+                    vals, _, gi = _kernel_ops.segmented_topk(
+                        qp, lp, self.arena.vectors, self.arena.label_words,
+                        self.arena.norms, self._rows_concat_dev, starts,
+                        lens, k=k, lmax=lmax, metric=self.metric,
+                        backend=self._seg_backend, fused=self._seg_fused,
+                        **self.arena.tier_kwargs())
+                clock.lap("launch")
                 # global ids resolved inside the traced program (sentinel n
                 # included): no host remap, and warmup covers the full path
                 pend.append((qids, vals, gi, g))
+            clock.lap("pack")   # the partition's last step
             # single synchronization point: every tier is already queued
-            for qids, d, gi, g in pend:
-                out_d[qids] = np.asarray(d)[:g]
-                out_i[qids] = np.asarray(gi)[:g]
+            with _trace.span("search.sync"):
+                for qids, d, gi, g in pend:
+                    out_d[qids] = np.asarray(d)[:g]
+                    out_i[qids] = np.asarray(gi)[:g]
+            clock.lap("sync")
             if telem:
-                record_search_telemetry(
-                    self, routed, qmasks, k, Q, t_start=t_start,
-                    t_route=t_route, seg_before=seg_before,
-                    tier_bucket=tier_bucket, min_bucket=min_bucket)
+                with _trace.span("search.telemetry"):
+                    record_search_telemetry(
+                        self, routed, qmasks, k, Q, clock,
+                        seg_before=seg_before, tier_bucket=tier_bucket,
+                        min_bucket=min_bucket)
             return out_d, out_i
 
         by_key: dict[tuple[int, ...], list[int]] = {}
@@ -647,27 +673,33 @@ class LabelHybridEngine:
             tomb = tomb_by_key.get(key) if tomb_by_key else None
             if tomb is not None:
                 extra = dict(search_params, tomb=tomb)
-            d, li = dispatch_padded(searcher, queries[qids], qwords[qids],
-                                    k, min_bucket=min_bucket, **extra)
+            with _trace.span("search.launch", queries=len(qids)):
+                d, li = dispatch_padded(searcher, queries[qids],
+                                        qwords[qids], k,
+                                        min_bucket=min_bucket, **extra)
             pend.append((qids, d, li, len(qids)))
+        clock.lap("launch")
 
         # deferred sync: every group's device work is queued before the
         # first host materialization, so XLA executes groups while the
         # host maps the finished ones (the looped oracle blocks per group)
-        for qids, d, li, g in pend:
-            rows = self.rows[routed[qids[0]]]
-            li = np.asarray(li)[:g]
-            if rows.size:
-                empty = li >= rows.size
-                gi = np.where(empty, n, rows[np.clip(li, 0, rows.size - 1)])
-                out_i[qids] = gi.astype(np.int32)
-            # rows.size == 0 (empty dataset edge): out_i already holds the
-            # sentinel n everywhere, nothing to map
-            out_d[qids] = np.asarray(d)[:g]
+        with _trace.span("search.sync"):
+            for qids, d, li, g in pend:
+                rows = self.rows[routed[qids[0]]]
+                li = np.asarray(li)[:g]
+                if rows.size:
+                    empty = li >= rows.size
+                    gi = np.where(empty, n,
+                                  rows[np.clip(li, 0, rows.size - 1)])
+                    out_i[qids] = gi.astype(np.int32)
+                # rows.size == 0 (empty dataset edge): out_i already holds
+                # the sentinel n everywhere, nothing to map
+                out_d[qids] = np.asarray(d)[:g]
+        clock.lap("sync")
         if telem:
-            record_search_telemetry(self, routed, qmasks, k, Q,
-                                    t_start=t_start, t_route=t_route,
-                                    min_bucket=min_bucket)
+            with _trace.span("search.telemetry"):
+                record_search_telemetry(self, routed, qmasks, k, Q, clock,
+                                        min_bucket=min_bucket)
         return out_d, out_i
 
     def arena_tier_batches(self, queries: np.ndarray, qwords: np.ndarray,
@@ -685,23 +717,26 @@ class LabelHybridEngine:
         streaming engine's tombstone-aware executor
         (``core.stream.StreamingEngine``) both iterate it, so the two
         executors run the identical tier/bucket decomposition by
-        construction."""
+        construction.  Each tier's sort and padding, and the partition
+        before them, is a ``search.pack`` span, closed before the yield."""
         tiers: dict[int, list[int]] = {}
-        for qi, key in enumerate(routed):
-            tiers.setdefault(pow2_bucket(self.segments[key][1]),
-                             []).append(qi)
+        with _trace.span("search.pack"):
+            for qi, key in enumerate(routed):
+                tiers.setdefault(pow2_bucket(self.segments[key][1]),
+                                 []).append(qi)
         for lmax in sorted(tiers):
-            qids = sorted(tiers[lmax],
-                          key=lambda qi: self.segments[routed[qi]][0])
-            g = len(qids)
-            bucket = pow2_bucket(g, min_bucket)
-            qp = np.zeros((bucket, queries.shape[1]), np.float32)
-            qp[:g] = queries[qids]
-            lp = np.zeros((bucket, qwords.shape[1]), np.int32)
-            lp[:g] = qwords[qids]
-            seg = np.zeros((2, bucket), np.int32)   # starts / lens
-            seg[:, :g] = np.array(
-                [self.segments[routed[qi]] for qi in qids], np.int32).T
+            with _trace.span("search.pack", lmax=lmax):
+                qids = sorted(tiers[lmax],
+                              key=lambda qi: self.segments[routed[qi]][0])
+                g = len(qids)
+                bucket = pow2_bucket(g, min_bucket)
+                qp = np.zeros((bucket, queries.shape[1]), np.float32)
+                qp[:g] = queries[qids]
+                lp = np.zeros((bucket, qwords.shape[1]), np.int32)
+                lp[:g] = qwords[qids]
+                seg = np.zeros((2, bucket), np.int32)   # starts / lens
+                seg[:, :g] = np.array(
+                    [self.segments[routed[qi]] for qi in qids], np.int32).T
             yield qids, qp, lp, seg[0], seg[1], lmax, g
 
     def search_looped(self, queries: np.ndarray,

@@ -79,7 +79,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from .adaptive import WorkloadMonitor, selection_from_weighted, weighted_select
 from .eis import EISResult
-from .engine import (LabelHybridEngine, publish_engine_gauges,
+from .engine import (LabelHybridEngine, StageClock, publish_engine_gauges,
                      record_search_telemetry)
 from .faults import faultpoint, register_fault_point
 from .groups import EMPTY_KEY, GroupTable
@@ -585,7 +585,7 @@ class StreamingEngine:
         ``search_padded(tomb=…)`` protocol (``_private_tombs``).
         """
         telem = _metrics.enabled() or _trace.enabled()
-        t_start = time.perf_counter() if telem else 0.0
+        clock = StageClock(telem)
         if self.monitor is not None:
             self.monitor.observe([tuple(ls) for ls in query_label_sets])
         if not self.lazy:
@@ -599,23 +599,25 @@ class StreamingEngine:
                             f"takes no search params; got "
                             f"{sorted(search_params)}")
         eng = self.base
-        queries = np.asarray(queries, dtype=np.float32)
-        Q = queries.shape[0]
-        n_base = len(eng.label_sets)
-        sentinel = check_global_id_contract(self.sentinel)
-        out_d = np.full((Q, k), np.inf, dtype=np.float32)
-        out_i = np.full((Q, k), sentinel, dtype=np.int32)
-        if Q == 0:
-            return out_d, out_i
+        with _trace.span("search.route", Q=len(queries),
+                         backend=eng.backend):
+            queries = np.asarray(queries, dtype=np.float32)
+            Q = queries.shape[0]
+            n_base = len(eng.label_sets)
+            sentinel = check_global_id_contract(self.sentinel)
+            out_d = np.full((Q, k), np.inf, dtype=np.float32)
+            out_i = np.full((Q, k), sentinel, dtype=np.int32)
+            if Q == 0:
+                return out_d, out_i
 
-        import jax.numpy as jnp
+            import jax.numpy as jnp
 
-        from ..index.base import pow2_bucket
+            from ..index.base import pow2_bucket
 
-        qmasks = encode_many(query_label_sets)
-        qwords = masks_to_int32_words(qmasks)
-        routed = eng.route_many(query_label_sets, qmasks)
-        t_route = time.perf_counter() if telem else 0.0
+            qmasks = encode_many(query_label_sets)
+            qwords = masks_to_int32_words(qmasks)
+            routed = eng.route_many(query_label_sets, qmasks)
+        clock.lap("route")
         seg_before = (_kernel_ops._segmented_topk._cache_size()
                       if telem else None)
         tier_bucket: dict[int, int] = {}
@@ -634,40 +636,50 @@ class StreamingEngine:
         base_g = jnp.full((qb, k), n_base, jnp.int32)
         for qids, qp, lp, starts, lens, lmax, g in \
                 eng.arena_tier_batches(queries, qwords, routed, min_bucket):
+            clock.lap("pack")
             if telem:
                 tier_bucket[lmax] = qp.shape[0]
-            bvals, _, bgid = _kernel_ops.segmented_topk(
-                qp, lp, eng.arena.vectors, eng.arena.label_words,
-                eng.arena.norms, eng._rows_concat_dev, starts, lens,
-                k=k, lmax=lmax, metric=eng.metric,
-                backend=eng._seg_backend, tomb=tomb,
-                fused=eng._seg_fused, **eng.arena.tier_kwargs())
-            idx = np.full(bvals.shape[0], qb, np.int32)
-            idx[:g] = qids                  # pad lanes scatter out of
-            base_v, base_g = _kernel_ops.scatter_topk_rows(
-                base_v, base_g, jnp.asarray(idx), bvals, bgid)
+            with _trace.span("search.launch", lmax=lmax,
+                             bucket=qp.shape[0]):
+                bvals, _, bgid = _kernel_ops.segmented_topk(
+                    qp, lp, eng.arena.vectors, eng.arena.label_words,
+                    eng.arena.norms, eng._rows_concat_dev, starts, lens,
+                    k=k, lmax=lmax, metric=eng.metric,
+                    backend=eng._seg_backend, tomb=tomb,
+                    fused=eng._seg_fused, **eng.arena.tier_kwargs())
+                idx = np.full(bvals.shape[0], qb, np.int32)
+                idx[:g] = qids                  # pad lanes scatter out of
+                base_v, base_g = _kernel_ops.scatter_topk_rows(
+                    base_v, base_g, jnp.asarray(idx), bvals, bgid)
+            clock.lap("launch")
+        clock.lap("pack")   # the partition's last step
         if delta.count:
-            qp_all = np.zeros((qb, queries.shape[1]), np.float32)
-            qp_all[:Q] = queries
-            lp_all = np.zeros((qb, qwords.shape[1]), np.int32)
-            lp_all[:Q] = qwords
-            dvals, dslot = _kernel_ops.delta_topk(
-                qp_all, lp_all, delta.vectors, delta.label_words,
-                delta.norms, delta.tombstones, delta.count, k=k,
-                metric=eng.metric, backend=eng._seg_backend,
-                fused=eng._seg_fused, **delta.tier_kwargs())
-            base_v, base_g = _kernel_ops.merge_topk(
-                base_v, base_g, dvals, dslot, n_base, sentinel, k=k)
+            with _trace.span("search.launch", delta=delta.count):
+                qp_all = np.zeros((qb, queries.shape[1]), np.float32)
+                qp_all[:Q] = queries
+                lp_all = np.zeros((qb, qwords.shape[1]), np.int32)
+                lp_all[:Q] = qwords
+                dvals, dslot = _kernel_ops.delta_topk(
+                    qp_all, lp_all, delta.vectors, delta.label_words,
+                    delta.norms, delta.tombstones, delta.count, k=k,
+                    metric=eng.metric, backend=eng._seg_backend,
+                    fused=eng._seg_fused, **delta.tier_kwargs())
+                base_v, base_g = _kernel_ops.merge_topk(
+                    base_v, base_g, dvals, dslot, n_base, sentinel, k=k)
+            clock.lap("launch")
         # empty delta: base_g's empty-slot id n_base IS the stream sentinel
-        out_d[:] = np.asarray(base_v)[:Q]
-        out_i[:] = np.asarray(base_g)[:Q]
+        with _trace.span("search.sync"):
+            out_d[:] = np.asarray(base_v)[:Q]
+            out_i[:] = np.asarray(base_g)[:Q]
+        clock.lap("sync")
         if telem:
             dead = int(self._base_dead.sum() + self._delta_dead.sum())
-            record_search_telemetry(
-                eng, routed, qmasks, k, Q, t_start=t_start, t_route=t_route,
-                seg_before=seg_before, tier_bucket=tier_bucket,
-                min_bucket=min_bucket,
-                tomb_density=dead / max(1, self.sentinel))
+            with _trace.span("search.telemetry"):
+                record_search_telemetry(
+                    eng, routed, qmasks, k, Q, clock,
+                    seg_before=seg_before, tier_bucket=tier_bucket,
+                    min_bucket=min_bucket,
+                    tomb_density=dead / max(1, self.sentinel))
         return out_d, out_i
 
     # -- warmup ---------------------------------------------------------------

@@ -107,40 +107,51 @@ def _lax_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens, tomb,
 
     def tile_fn(tile):
         qt, lqt, st, ln = tile
-        qn = jnp.sum(qt * qt, axis=1)
+        with jax.named_scope("scan.distance"):
+            qn = jnp.sum(qt * qt, axis=1)
         init = (jnp.full((qtile, kp), jnp.inf, jnp.float32),
                 jnp.full((qtile, kp), lmax, jnp.int32))
 
         def body(carry, c0):
             run_v, run_p = carry
-            pos = c0 + jnp.arange(chunk, dtype=jnp.int32)        # [C]
-            valid = pos[None, :] < ln[:, None]                   # [T, C]
-            p = jnp.clip(st[:, None] + pos[None, :], 0, max(R - 1, 0))
-            gid = rows_concat[jnp.where(valid, p, 0)]            # [T, C]
-            xg = ref.dequantize_rows(
-                ax[gid], dtype,
-                None if scales is None else scales[gid],
-                None if zeros is None else zeros[gid])           # [T, C, D]
+            with jax.named_scope("scan.row_ids"):
+                pos = c0 + jnp.arange(chunk, dtype=jnp.int32)    # [C]
+                valid = pos[None, :] < ln[:, None]               # [T, C]
+                p = jnp.clip(st[:, None] + pos[None, :], 0, max(R - 1, 0))
+                gid = rows_concat[jnp.where(valid, p, 0)]        # [T, C]
+            with jax.named_scope("scan.rows"):
+                xg = ref.dequantize_rows(
+                    ax[gid], dtype,
+                    None if scales is None else scales[gid],
+                    None if zeros is None else zeros[gid])       # [T, C, D]
             # multiply + minor-axis reduce, NOT dot_general: per-element
             # f32 accumulation, independent of the (qtile, chunk) tiling —
             # the bit-parity the fused/unfused equivalence rests on
-            ip = jnp.sum(xg * qt[:, None, :], axis=-1)
-            d = -ip if metric == "ip" else \
-                qn[:, None] - 2.0 * ip + axn[gid]
-            keep = jnp.all((lqt[:, None, :] & alw[gid]) == lqt[:, None, :],
-                           axis=-1)
-            if tomb is not None:
-                keep = keep & ref.tombstone_mask(tomb, gid)
-            d = jnp.where(keep & valid, d, jnp.inf)
+            with jax.named_scope("scan.distance"):
+                ip = jnp.sum(xg * qt[:, None, :], axis=-1)
+                d = -ip if metric == "ip" else qn[:, None] - 2.0 * ip
+            if metric == "l2":
+                with jax.named_scope("scan.norms"):
+                    xn = axn[gid]
+                with jax.named_scope("scan.distance"):
+                    d = d + xn
+            with jax.named_scope("scan.label_words"):
+                keep = jnp.all(
+                    (lqt[:, None, :] & alw[gid]) == lqt[:, None, :],
+                    axis=-1)
+                if tomb is not None:
+                    keep = keep & ref.tombstone_mask(tomb, gid)
+                d = jnp.where(keep & valid, d, jnp.inf)
             # running-pool merge: running entries hold strictly earlier
             # positions and lax.top_k prefers the lower concat index on
             # ties, preserving (distance, position) order chunk by chunk
-            cat_v = jnp.concatenate([run_v, d], axis=1)
-            cat_p = jnp.concatenate(
-                [run_p, jnp.broadcast_to(pos[None, :], (qtile, chunk))],
-                axis=1)
-            neg, sel = jax.lax.top_k(-cat_v, kp)
-            return (-neg, jnp.take_along_axis(cat_p, sel, axis=1)), None
+            with jax.named_scope("scan.merge"):
+                cat_v = jnp.concatenate([run_v, d], axis=1)
+                cat_p = jnp.concatenate(
+                    [run_p, jnp.broadcast_to(pos[None, :], (qtile, chunk))],
+                    axis=1)
+                neg, sel = jax.lax.top_k(-cat_v, kp)
+                return (-neg, jnp.take_along_axis(cat_p, sel, axis=1)), None
 
         (v, p), _ = jax.lax.scan(body, init, steps)
         return v, p
@@ -349,11 +360,14 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
     # a chunk that holds rows of its segment starts below the table's end,
     # so its window is read where it lies; starts past the end (chunks past
     # a segment, which gather no rows) are clamped to stay in range
-    rc, max_start = pad_id_table(rows_concat, chunk)
-    ax, side, layout = scan_operands(
-        ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
-        dtype=dtype)
-    q = pack_query(q, layout)
+    with jax.named_scope("scan.row_ids"):
+        rc, max_start = pad_id_table(rows_concat, chunk)
+    with jax.named_scope("scan.sidecar"):
+        ax, side, layout = scan_operands(
+            ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
+            dtype=dtype)
+    with jax.named_scope("scan.distance"):
+        q = pack_query(q, layout)
 
     scratch = [
         pltpu.SMEM((qtile * win,), jnp.int32),             # id windows
@@ -447,12 +461,15 @@ def _pallas_fused_scan(q, lq, ax, alw, axn, rows_concat, starts, lens,
                    pl.BlockSpec((qtile, kp), im)],
         scratch_shapes=scratch,
     )
-    vals, pos = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((Q, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, kp), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32),
-      q, lq, *operands)
+    # one kernel fuses the id windows, the row gather, the distances and
+    # the merge: it is named for the row gather as a whole
+    with jax.named_scope("scan.rows"):
+        vals, pos = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((Q, kp), jnp.float32),
+                       jax.ShapeDtypeStruct((Q, kp), jnp.int32)],
+            interpret=interpret,
+        )(jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32),
+          q, lq, *operands)
     return vals, pos

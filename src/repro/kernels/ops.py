@@ -182,50 +182,63 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     # shortlist width: k' bounded by the span (a span-sized shortlist is
     # already exhaustive), never below k (the output width)
     kp = k if rr is None else max(k, min(kprime or 4 * k, lmax))
-    qn = jnp.sum(q * q, axis=1)
+    with jax.named_scope("scan.distance"):
+        qn = jnp.sum(q * q, axis=1)
     init = (jnp.full((Q, kp), jnp.inf, jnp.float32),
             jnp.full((Q, kp), lmax, jnp.int32))
 
     if backend == "pallas" and not fused:
-        sx, side, layout = scan_operands(
-            ax, alw, axn if metric == "l2" else None, scales, zeros, tomb,
-            dtype=dtype)
+        with jax.named_scope("scan.sidecar"):
+            sx, side, layout = scan_operands(
+                ax, alw, axn if metric == "l2" else None, scales, zeros,
+                tomb, dtype=dtype)
 
     def body(carry, c0):  # unfused scan stage (fused=False)
         run_v, run_p = carry
-        pos = c0 + jnp.arange(chunk, dtype=jnp.int32)          # [C]
-        valid = pos[None, :] < lens[:, None]                   # [Q, C]
-        p = jnp.clip(starts[:, None] + pos[None, :], 0, max(R - 1, 0))
-        gid = rows_concat[jnp.where(valid, p, 0)]              # [Q, C]
+        with jax.named_scope("scan.row_ids"):
+            pos = c0 + jnp.arange(chunk, dtype=jnp.int32)      # [C]
+            valid = pos[None, :] < lens[:, None]               # [Q, C]
+            p = jnp.clip(starts[:, None] + pos[None, :], 0, max(R - 1, 0))
+            gid = rows_concat[jnp.where(valid, p, 0)]          # [Q, C]
         if backend == "pallas":
             # the kernel fuses label + tombstone filter and the lens mask
-            d = segmented_gather_distance_pallas(
-                q, lq, sx, side, gid, jnp.clip(lens - c0, 0, chunk),
-                layout=layout, metric=metric, dtype=dtype,
-                interpret=interpret, dcols=dcols)
+            with jax.named_scope("scan.rows"):
+                d = segmented_gather_distance_pallas(
+                    q, lq, sx, side, gid, jnp.clip(lens - c0, 0, chunk),
+                    layout=layout, metric=metric, dtype=dtype,
+                    interpret=interpret, dcols=dcols)
         else:
-            xg = ref.dequantize_rows(
-                ax[gid], dtype,
-                None if scales is None else scales[gid],
-                None if zeros is None else zeros[gid])         # [Q, C, D]
+            with jax.named_scope("scan.rows"):
+                xg = ref.dequantize_rows(
+                    ax[gid], dtype,
+                    None if scales is None else scales[gid],
+                    None if zeros is None else zeros[gid])     # [Q, C, D]
             # explicit multiply + minor-axis reduce, NOT a dot_general: XLA
             # tiles batched contractions differently per batch size, which
             # perturbs f32 accumulation order at ULP level — a reduce over
             # the contiguous minor dim is per-element and therefore
             # batch-composition independent, which the executor's
             # bit-parity contract (batched == looped) depends on
-            ip = jnp.sum(xg * q[:, None, :], axis=-1)
-            d = -ip if metric == "ip" else qn[:, None] - 2.0 * ip + axn[gid]
-            keep = jnp.all((lq[:, None, :] & alw[gid]) == lq[:, None, :],
-                           axis=-1)
-            if tomb is not None:
-                keep = keep & ref.tombstone_mask(tomb, gid)
-            d = jnp.where(keep & valid, d, jnp.inf)
-        cat_v = jnp.concatenate([run_v, d], axis=1)
-        cat_p = jnp.concatenate(
-            [run_p, jnp.broadcast_to(pos[None, :], (Q, chunk))], axis=1)
-        neg, sel = jax.lax.top_k(-cat_v, kp)
-        return (-neg, jnp.take_along_axis(cat_p, sel, axis=1)), None
+            with jax.named_scope("scan.distance"):
+                ip = jnp.sum(xg * q[:, None, :], axis=-1)
+                d = -ip if metric == "ip" else qn[:, None] - 2.0 * ip
+            if metric == "l2":
+                with jax.named_scope("scan.norms"):
+                    xn = axn[gid]
+                with jax.named_scope("scan.distance"):
+                    d = d + xn
+            with jax.named_scope("scan.label_words"):
+                keep = jnp.all((lq[:, None, :] & alw[gid]) == lq[:, None, :],
+                               axis=-1)
+                if tomb is not None:
+                    keep = keep & ref.tombstone_mask(tomb, gid)
+                d = jnp.where(keep & valid, d, jnp.inf)
+        with jax.named_scope("scan.merge"):
+            cat_v = jnp.concatenate([run_v, d], axis=1)
+            cat_p = jnp.concatenate(
+                [run_p, jnp.broadcast_to(pos[None, :], (Q, chunk))], axis=1)
+            neg, sel = jax.lax.top_k(-cat_v, kp)
+            return (-neg, jnp.take_along_axis(cat_p, sel, axis=1)), None
 
     if fused:
         # fused scan stage (DESIGN.md §3.9): same chunk schedule, but the
@@ -248,41 +261,46 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         # position) — position-ascending input makes lax.top_k's
         # lower-index preference reproduce exactly that (empties, pos ==
         # lmax, sort to the tail)
-        spos = jnp.sort(pos, axis=1)
-        listed = spos < lmax
-        sp = jnp.clip(starts[:, None] + spos, 0, max(R - 1, 0))
-        sgid = rows_concat[jnp.where(listed, sp, 0)]           # [Q, kp]
-        if backend == "pallas":
-            # shortlist rows already passed the label/tombstone filter;
-            # position-sorted means the first sum(listed) lanes are the
-            # live ones, which is exactly the kernel's lens mask
-            rside, rlayout = row_sidecar(
-                alw, rrn if metric == "l2" else None)
-            d = segmented_gather_distance_pallas(
-                q, lq, rr, rside, sgid,
-                jnp.sum(listed, axis=1).astype(jnp.int32),
-                layout=rlayout, metric=metric, interpret=interpret)
-        else:
-            xg = rr[sgid]                                      # [Q, kp, D]
-            ip = jnp.sum(xg * q[:, None, :], axis=-1)
-            d = -ip if metric == "ip" else \
-                qn[:, None] - 2.0 * ip + rrn[sgid]
-            d = jnp.where(listed, d, jnp.inf)
-        if kp < k:   # lmax < k: pad the shortlist out to the output width
-            d = jnp.pad(d, ((0, 0), (0, k - kp)), constant_values=jnp.inf)
-            spos = jnp.pad(spos, ((0, 0), (0, k - kp)), constant_values=lmax)
-        neg, sel = jax.lax.top_k(-d, k)
-        vals = -neg
-        pos = jnp.take_along_axis(spos, sel, axis=1)
-    empty = jnp.isinf(vals)
-    pos = jnp.where(empty, lmax, pos)
-    vals = jnp.where(empty, jnp.float32(jnp.inf), vals)
-    # resolve global ids inside the traced program (empty slot -> the
-    # arena-cardinality sentinel), so the executor never touches ids on
-    # host and warmup covers the whole path
-    gid = jnp.where(empty, ax.shape[0],
-                    rows_concat[jnp.clip(starts[:, None] + pos, 0,
-                                         max(R - 1, 0))])
+        with jax.named_scope("rerank.rows"):
+            spos = jnp.sort(pos, axis=1)
+            listed = spos < lmax
+            sp = jnp.clip(starts[:, None] + spos, 0, max(R - 1, 0))
+            sgid = rows_concat[jnp.where(listed, sp, 0)]       # [Q, kp]
+            if backend == "pallas":
+                # shortlist rows already passed the label/tombstone filter;
+                # position-sorted means the first sum(listed) lanes are the
+                # live ones, which is exactly the kernel's lens mask
+                rside, rlayout = row_sidecar(
+                    alw, rrn if metric == "l2" else None)
+                d = segmented_gather_distance_pallas(
+                    q, lq, rr, rside, sgid,
+                    jnp.sum(listed, axis=1).astype(jnp.int32),
+                    layout=rlayout, metric=metric, interpret=interpret)
+            else:
+                xg = rr[sgid]                                  # [Q, kp, D]
+                ip = jnp.sum(xg * q[:, None, :], axis=-1)
+                d = -ip if metric == "ip" else \
+                    qn[:, None] - 2.0 * ip + rrn[sgid]
+                d = jnp.where(listed, d, jnp.inf)
+        with jax.named_scope("rerank.merge"):
+            if kp < k:   # lmax < k: pad the shortlist out to the output width
+                d = jnp.pad(d, ((0, 0), (0, k - kp)),
+                            constant_values=jnp.inf)
+                spos = jnp.pad(spos, ((0, 0), (0, k - kp)),
+                               constant_values=lmax)
+            neg, sel = jax.lax.top_k(-d, k)
+            vals = -neg
+            pos = jnp.take_along_axis(spos, sel, axis=1)
+    with jax.named_scope("scan.resolve_ids"):
+        empty = jnp.isinf(vals)
+        pos = jnp.where(empty, lmax, pos)
+        vals = jnp.where(empty, jnp.float32(jnp.inf), vals)
+        # resolve global ids inside the traced program (empty slot -> the
+        # arena-cardinality sentinel), so the executor never touches ids on
+        # host and warmup covers the whole path
+        gid = jnp.where(empty, ax.shape[0],
+                        rows_concat[jnp.clip(starts[:, None] + pos, 0,
+                                             max(R - 1, 0))])
     return vals, pos.astype(jnp.int32), gid.astype(jnp.int32)
 
 
@@ -448,14 +466,15 @@ def _merge_topk(bv, bi, dv, dslot, base_offset, sentinel, *, k):
     union.  Empty slots resolve to ``sentinel`` (the stream cardinality,
     traced so inserts don't retrace) with +inf distance.
     """
-    cat_v = jnp.concatenate([bv, dv], axis=1)
-    cat_i = jnp.concatenate([bi, base_offset + dslot], axis=1)
-    neg, sel = jax.lax.top_k(-cat_v, k)
-    vals = -neg
-    ids = jnp.take_along_axis(cat_i, sel, axis=1)
-    empty = jnp.isinf(vals)
-    ids = jnp.where(empty, sentinel, ids)
-    vals = jnp.where(empty, jnp.float32(jnp.inf), vals)
+    with jax.named_scope("delta.merge"):
+        cat_v = jnp.concatenate([bv, dv], axis=1)
+        cat_i = jnp.concatenate([bi, base_offset + dslot], axis=1)
+        neg, sel = jax.lax.top_k(-cat_v, k)
+        vals = -neg
+        ids = jnp.take_along_axis(cat_i, sel, axis=1)
+        empty = jnp.isinf(vals)
+        ids = jnp.where(empty, sentinel, ids)
+        vals = jnp.where(empty, jnp.float32(jnp.inf), vals)
     return vals, ids.astype(jnp.int32)
 
 

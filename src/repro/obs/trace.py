@@ -8,10 +8,13 @@ timestamps relative to the tracer epoch; query cards ride along under a
 Two entry styles (DESIGN.md §6.2):
 
 - ``with span("durability.snapshot", rows=n): ...`` — context-manager
-  spans for code that is cheap to wrap.
+  spans for code that is cheap to wrap.  While tracing is on, each also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so that a
+  profiler trace holds it on the host plane, on the device's clock.
 - ``get_tracer().complete(name, t0, t1, **args)`` — retro-logged spans
   for hot paths that already collect ``perf_counter`` timestamps for
-  metrics; no nesting rewrite, no overhead when tracing is off.
+  metrics; no nesting rewrite, no overhead when tracing is off, and no
+  profiler annotation.
 
 A *query card* is the per-batch accounting record the paper's claims
 live or die on: which index key each query key routed to, the realized
@@ -21,8 +24,9 @@ rerank shortlist size, tombstone density, and whether the batch
 triggered a ``_segmented_topk`` recompile (``_cache_size()`` delta).
 
 Tracing defaults OFF (it allocates one dict per span); enabling it
-must not change search bits or traces — everything here is plain host
-Python.
+must not change search bits or traces — everything here is host work:
+no instrument touches the device, and JAX is imported only once a span
+opens while tracing is on.
 """
 
 from __future__ import annotations
@@ -165,19 +169,24 @@ def reset() -> None:
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    __slots__ = ("name", "args", "t0", "note")
 
     def __init__(self, name: str, args: dict[str, Any]):
         self.name = name
         self.args = args
 
     def __enter__(self) -> _Span:
+        # imported here: tracing is on, and with it off nothing loads jax
+        from jax.profiler import TraceAnnotation
+        self.note = TraceAnnotation(self.name)
+        self.note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        _TRACER.complete(self.name, self.t0, time.perf_counter(),
-                         **self.args)
+        t1 = time.perf_counter()
+        self.note.__exit__(*exc)
+        _TRACER.complete(self.name, self.t0, t1, **self.args)
 
 
 class _NullSpan:
@@ -194,7 +203,8 @@ _NULL = _NullSpan()
 
 
 def span(name: str, **args: Any) -> _Span | _NullSpan:
-    """``with span("route", backend="flat"): ...`` — no-op when
+    """``with span("route", backend="flat"): ...`` — a Chrome-trace
+    event and a profiler annotation of the same name; a no-op when
     tracing is disabled (returns a shared null context manager)."""
     if not _enabled:
         return _NULL

@@ -109,6 +109,10 @@ _M_ACHIEVED = _metrics.gauge(
     "eli_elastic_factor_achieved",
     "min realized elastic factor over the selection workload (stats())",
 )
+_M_IN_PLACE = _metrics.counter(
+    "eli_scan_in_place_queries_total",
+    "real queries whose base scan read the arena in place (DESIGN.md §3.10)",
+)
 
 
 class StageClock:
@@ -163,6 +167,7 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
             _M_EF_BOUND.set(bound)
 
     tracing = _trace.enabled()
+    in_place = engine.in_place_keys
     # group the batch by (query key, routed key): every query in a group
     # pays the same elastic factor, so one observe/card per group amortizes
     # the host cost on large batches
@@ -177,6 +182,8 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
         if qsize and ssize:
             factor = qsize / ssize
         if _metrics.enabled():
+            if skey in in_place:
+                _M_IN_PLACE.inc(count)
             if factor is not None:
                 _M_EF.labels(backend).observe(factor, n=count)
                 if bound is not None and factor < bound - 1e-12:
@@ -188,7 +195,7 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
             span_tier = (pow2_bucket(seg[1])
                          if seg is not None and arena is not None else None)
             if tier_bucket is not None and span_tier is not None:
-                q_bucket = tier_bucket.get(span_tier)
+                q_bucket = tier_bucket.get((span_tier, skey in in_place))
             else:
                 q_bucket = pow2_bucket(count, min_bucket)
             shortlist = None
@@ -200,7 +207,9 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
                 elastic_factor=factor, bound=bound, span_tier=span_tier,
                 q_bucket=q_bucket, dtype=dtype, shortlist=shortlist,
                 tombstone_density=tomb_density,
-                recompiled=seg_delta > 0, backend=backend))
+                recompiled=seg_delta > 0, backend=backend,
+                scan=(None if span_tier is None else
+                      "in_place" if skey in in_place else "row_table")))
 
 
 def publish_engine_gauges(st) -> None:
@@ -382,6 +391,16 @@ class LabelHybridEngine:
             off += rows.size
         self.rows_concat = (np.concatenate(parts) if parts
                             else np.zeros(0, np.int32))
+        # segments the unfused XLA scan reads in place (DESIGN.md §3.10):
+        # exactly those whose row list is the identity arange(n) — the
+        # root, whether built here or handed over through rows_hint
+        self.in_place_keys = frozenset()
+        if self._arena_native and _kernel_ops.in_place_capable(
+                self._seg_backend, self._seg_fused):
+            ident = np.arange(n, dtype=np.int32)
+            self.in_place_keys = frozenset(
+                key for key, rows in self.rows.items()
+                if np.array_equal(rows, ident))
         # the device copy of the CSR table feeds the segmented kernel and
         # the views; private-storage backends never read it on device, so
         # they skip the upload (and its HBM) entirely
@@ -627,13 +646,13 @@ class LabelHybridEngine:
                                 f"{sorted(search_params)}")
             seg_before = (_kernel_ops._segmented_topk._cache_size()
                           if telem else None)
-            tier_bucket: dict[int, int] = {}
-            for qids, qp, lp, starts, lens, lmax, g in \
+            tier_bucket: dict[tuple[int, bool], int] = {}
+            for qids, qp, lp, starts, lens, lmax, in_place, g in \
                     self.arena_tier_batches(queries, qwords, routed,
                                             min_bucket):
                 clock.lap("pack")
                 if telem:
-                    tier_bucket[lmax] = qp.shape[0]
+                    tier_bucket[lmax, in_place] = qp.shape[0]
                 with _trace.span("search.launch", lmax=lmax,
                                  bucket=qp.shape[0]):
                     vals, _, gi = _kernel_ops.segmented_topk(
@@ -641,7 +660,7 @@ class LabelHybridEngine:
                         self.arena.norms, self._rows_concat_dev, starts,
                         lens, k=k, lmax=lmax, metric=self.metric,
                         backend=self._seg_backend, fused=self._seg_fused,
-                        **self.arena.tier_kwargs())
+                        in_place=in_place, **self.arena.tier_kwargs())
                 clock.lap("launch")
                 # global ids resolved inside the traced program (sentinel n
                 # included): no host remap, and warmup covers the full path
@@ -708,25 +727,27 @@ class LabelHybridEngine:
         """Partition a routed batch by candidate-span tier and yield the
         padded segmented-program operands per tier:
 
-            (qids, qp, lp, starts, lens, lmax, g)
+            (qids, qp, lp, starts, lens, lmax, in_place, g)
 
         — queries sorted by segment start within a tier (gather locality),
         zero-padded to the power-of-two Q-bucket, with each query's
-        ``(start, len)`` CSR segment.  The single home of the arena
-        executor's partition+padding convention: ``search_batched`` and the
-        streaming engine's tombstone-aware executor
-        (``core.stream.StreamingEngine``) both iterate it, so the two
-        executors run the identical tier/bucket decomposition by
+        ``(start, len)`` CSR segment.  Queries routed to an in-place
+        segment (``in_place_keys``) form a batch of their own within
+        their span tier, yielded with ``in_place=True``.  The single home
+        of the arena executor's partition+padding convention:
+        ``search_batched`` and the streaming engine's tombstone-aware
+        executor (``core.stream.StreamingEngine``) both iterate it, so the
+        two executors run the identical tier/bucket decomposition by
         construction.  Each tier's sort and padding, and the partition
         before them, is a ``search.pack`` span, closed before the yield."""
-        tiers: dict[int, list[int]] = {}
+        tiers: dict[tuple[int, bool], list[int]] = {}
         with _trace.span("search.pack"):
             for qi, key in enumerate(routed):
-                tiers.setdefault(pow2_bucket(self.segments[key][1]),
-                                 []).append(qi)
-        for lmax in sorted(tiers):
+                tiers.setdefault((pow2_bucket(self.segments[key][1]),
+                                  key in self.in_place_keys), []).append(qi)
+        for lmax, in_place in sorted(tiers):
             with _trace.span("search.pack", lmax=lmax):
-                qids = sorted(tiers[lmax],
+                qids = sorted(tiers[lmax, in_place],
                               key=lambda qi: self.segments[routed[qi]][0])
                 g = len(qids)
                 bucket = pow2_bucket(g, min_bucket)
@@ -737,7 +758,7 @@ class LabelHybridEngine:
                 seg = np.zeros((2, bucket), np.int32)   # starts / lens
                 seg[:, :g] = np.array(
                     [self.segments[routed[qi]] for qi in qids], np.int32).T
-            yield qids, qp, lp, seg[0], seg[1], lmax, g
+            yield qids, qp, lp, seg[0], seg[1], lmax, in_place, g
 
     def search_looped(self, queries: np.ndarray,
                       query_label_sets: Sequence[tuple[int, ...]], k: int,
@@ -786,8 +807,10 @@ class LabelHybridEngine:
 
           * arena-native backends: the segmented program for every
             (k ∈ ks, Q-bucket ∈ buckets, candidate-span tier) triple — span
-            tiers are known at build time from the segment table, and the
-            same executables also serve the per-view looped path;
+            tiers are known at build time from the segment table — in the
+            read path the executor takes there: in place for the tiers of
+            ``in_place_keys``, through the row table for tiers that hold
+            other segments (those also serve the per-view looped path);
           * private-storage backends: every selected index's
             ``search_padded`` per (k, bucket).
 
@@ -809,8 +832,7 @@ class LabelHybridEngine:
         D = self.vectors.shape[1]
         W = self.label_words.shape[1]
         outs: list[object] = []
-        span_tiers = sorted({pow2_bucket(length)
-                             for _, length in self.segments.values()})
+        span_tiers = self.span_tiers()
         for k in ks:
             for b in buckets:
                 bucket = pow2_bucket(b)
@@ -818,14 +840,14 @@ class LabelHybridEngine:
                 lz = np.zeros((bucket, W), np.int32)
                 if self._arena_native and self.arena is not None:
                     zero = jnp.zeros(bucket, jnp.int32)
-                    for lmax in span_tiers:
+                    for lmax, in_place in span_tiers:
                         vals, _, _ = _kernel_ops.segmented_topk(
                             qz, lz, self.arena.vectors,
                             self.arena.label_words, self.arena.norms,
                             self._rows_concat_dev, zero, zero, k=k,
                             lmax=lmax, metric=self.metric,
                             backend=self._seg_backend, fused=self._seg_fused,
-                            **self.arena.tier_kwargs())
+                            in_place=in_place, **self.arena.tier_kwargs())
                         outs.append(vals)
                 else:
                     for index in self.indexes.values():
@@ -845,6 +867,12 @@ class LabelHybridEngine:
         for o in outs:
             jax.block_until_ready(jnp.asarray(o))
         return {"seconds": time.perf_counter() - t0, "programs": len(outs)}
+
+    def span_tiers(self) -> list[tuple[int, bool]]:
+        """The (span tier, in place) launches ``arena_tier_batches`` can
+        yield for this selection — what the warm-ups compile."""
+        return sorted({(pow2_bucket(length), key in self.in_place_keys)
+                       for key, (_, length) in self.segments.items()})
 
     def warmup_serving(self, ks: Sequence[int], min_bucket: int,
                        max_batch: int, **search_params) -> dict:

@@ -620,7 +620,7 @@ class StreamingEngine:
         clock.lap("route")
         seg_before = (_kernel_ops._segmented_topk._cache_size()
                       if telem else None)
-        tier_bucket: dict[int, int] = {}
+        tier_bucket: dict[tuple[int, bool], int] = {}
         delta = self.delta
         # tombstone mask only when base deletes are actually pending: the
         # un-deleted stream then runs the exact static program (zero mask
@@ -634,11 +634,11 @@ class StreamingEngine:
         qb = pow2_bucket(Q, min_bucket)
         base_v = jnp.full((qb, k), jnp.inf, jnp.float32)
         base_g = jnp.full((qb, k), n_base, jnp.int32)
-        for qids, qp, lp, starts, lens, lmax, g in \
+        for qids, qp, lp, starts, lens, lmax, in_place, g in \
                 eng.arena_tier_batches(queries, qwords, routed, min_bucket):
             clock.lap("pack")
             if telem:
-                tier_bucket[lmax] = qp.shape[0]
+                tier_bucket[lmax, in_place] = qp.shape[0]
             with _trace.span("search.launch", lmax=lmax,
                              bucket=qp.shape[0]):
                 bvals, _, bgid = _kernel_ops.segmented_topk(
@@ -646,7 +646,8 @@ class StreamingEngine:
                     eng.arena.norms, eng._rows_concat_dev, starts, lens,
                     k=k, lmax=lmax, metric=eng.metric,
                     backend=eng._seg_backend, tomb=tomb,
-                    fused=eng._seg_fused, **eng.arena.tier_kwargs())
+                    fused=eng._seg_fused, in_place=in_place,
+                    **eng.arena.tier_kwargs())
                 idx = np.full(bvals.shape[0], qb, np.int32)
                 idx[:g] = qids                  # pad lanes scatter out of
                 base_v, base_g = _kernel_ops.scatter_topk_rows(
@@ -708,8 +709,7 @@ class StreamingEngine:
         eng, delta = self.base, self.delta
         D = eng.vectors.shape[1]
         W = eng.label_words.shape[1]
-        span_tiers = sorted({pow2_bucket(length)
-                             for _, length in eng.segments.values()})
+        span_tiers = eng.span_tiers()
         outs: list[object] = []
         for k in ks:
             for b in buckets:
@@ -723,7 +723,7 @@ class StreamingEngine:
                     backend=eng._seg_backend, fused=eng._seg_fused,
                     **delta.tier_kwargs())
                 outs.append(dvals)
-                for lmax in span_tiers:
+                for lmax, in_place in span_tiers:
                     # both tombstone variants: the executor flips between
                     # them as deletes arrive / compactions clear them
                     for tomb in (None, eng.arena.tombstones):
@@ -733,7 +733,7 @@ class StreamingEngine:
                             eng._rows_concat_dev, zero, zero,
                             k=k, lmax=lmax, metric=eng.metric,
                             backend=eng._seg_backend, tomb=tomb,
-                            fused=eng._seg_fused,
+                            fused=eng._seg_fused, in_place=in_place,
                             **eng.arena.tier_kwargs())
                         outs.append(bvals)
                 mv, _ = _kernel_ops.merge_topk(

@@ -132,16 +132,24 @@ def _masked_distance_topk(d, tomb, n: int, *, k: int):
 SEG_CHUNK = 2048
 
 
+def in_place_capable(backend: str, fused: bool) -> bool:
+    """True ⇔ the scan program has an in-place read path for this
+    (backend, fused) choice: only the unfused XLA body does; the Pallas
+    and fused bodies always read through the row table."""
+    return backend != "pallas" and not fused
+
+
 @functools.partial(jax.jit, static_argnames=("k", "lmax", "chunk", "metric",
                                              "backend", "interpret", "dtype",
                                              "kprime", "dcols", "fused",
-                                             "qtile"))
+                                             "qtile", "in_place"))
 def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
                     tomb=None, scales=None, zeros=None, rr=None, rrn=None, *,
                     k: int, lmax: int, chunk: int, metric: str, backend: str,
                     interpret: bool, dtype: str = "f32",
                     kprime: int | None = None, dcols: int | None = None,
-                    fused: bool = False, qtile: int | None = None):
+                    fused: bool = False, qtile: int | None = None,
+                    in_place: bool = False):
     """Chunked segmented arena top-k — bit-identical to the unchunked
     oracle ``ref.segmented_filtered_topk``.
 
@@ -172,13 +180,29 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     rerank tier, and the final top-k comes out of the SAME traced program
     — one dispatch per (k, Q-bucket, span tier, dtype), and warmup covers
     scan + rerank together.
+
+    ``in_place`` (DESIGN.md §3.10; unfused XLA body only): every query's
+    segment is the identity over the arena, position p IS arena row p.
+    ``rows_concat``/``starts`` are then not read: each chunk is one
+    contiguous ``[chunk, ·]`` slice of the arena operands that the whole
+    batch shares, ``gid == pos``, and the distance, filter and merge are
+    the row-table body's — same bits.  The span is ``min(lmax, N)``; the
+    last chunk's slice start is clamped into the arena and the rows it
+    re-reads below the chunk's own start are masked out.
     """
     Q = q.shape[0]
-    R = rows_concat.shape[0]
     if lmax % chunk:
         raise ValueError(f"chunk {chunk} must divide lmax {lmax}")
     if metric not in ("l2", "ip"):
         raise ValueError(f"unknown metric {metric!r}")
+    if in_place and (fused or backend == "pallas"):
+        raise ValueError("the in-place scan is a read path of the unfused "
+                         "XLA body only")
+    N = ax.shape[0]
+    R = None if in_place else rows_concat.shape[0]
+    span = min(lmax, N) if in_place else lmax
+    if in_place:
+        chunk = min(chunk, span)
     # shortlist width: k' bounded by the span (a span-sized shortlist is
     # already exhaustive), never below k (the output width)
     kp = k if rr is None else max(k, min(kprime or 4 * k, lmax))
@@ -196,10 +220,27 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     def body(carry, c0):  # unfused scan stage (fused=False)
         run_v, run_p = carry
         with jax.named_scope("scan.row_ids"):
-            pos = c0 + jnp.arange(chunk, dtype=jnp.int32)      # [C]
-            valid = pos[None, :] < lens[:, None]               # [Q, C]
-            p = jnp.clip(starts[:, None] + pos[None, :], 0, max(R - 1, 0))
-            gid = rows_concat[jnp.where(valid, p, 0)]          # [Q, C]
+            if in_place:
+                # one contiguous chunk shared by the batch; the clamp keeps
+                # the tail slice inside the arena, and the rows it re-reads
+                # below c0 were merged by the previous chunk
+                r0 = jnp.minimum(c0, N - chunk)
+                pos = r0 + jnp.arange(chunk, dtype=jnp.int32)  # [C]
+                valid = ((pos >= c0)[None, :]
+                         & (pos[None, :] < lens[:, None]))     # [Q, C]
+                gid = pos[None, :]                             # [1, C]
+
+                def rows(a):
+                    return jax.lax.dynamic_slice_in_dim(a, r0, chunk)[None]
+            else:
+                pos = c0 + jnp.arange(chunk, dtype=jnp.int32)  # [C]
+                valid = pos[None, :] < lens[:, None]           # [Q, C]
+                p = jnp.clip(starts[:, None] + pos[None, :], 0,
+                             max(R - 1, 0))
+                gid = rows_concat[jnp.where(valid, p, 0)]      # [Q, C]
+
+                def rows(a):
+                    return a[gid]
         if backend == "pallas":
             # the kernel fuses label + tombstone filter and the lens mask
             with jax.named_scope("scan.rows"):
@@ -210,9 +251,9 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         else:
             with jax.named_scope("scan.rows"):
                 xg = ref.dequantize_rows(
-                    ax[gid], dtype,
-                    None if scales is None else scales[gid],
-                    None if zeros is None else zeros[gid])     # [Q, C, D]
+                    rows(ax), dtype,
+                    None if scales is None else rows(scales),
+                    None if zeros is None else rows(zeros))    # [Q|1, C, D]
             # explicit multiply + minor-axis reduce, NOT a dot_general: XLA
             # tiles batched contractions differently per batch size, which
             # perturbs f32 accumulation order at ULP level — a reduce over
@@ -224,12 +265,12 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
                 d = -ip if metric == "ip" else qn[:, None] - 2.0 * ip
             if metric == "l2":
                 with jax.named_scope("scan.norms"):
-                    xn = axn[gid]
+                    xn = rows(axn)
                 with jax.named_scope("scan.distance"):
                     d = d + xn
             with jax.named_scope("scan.label_words"):
-                keep = jnp.all((lq[:, None, :] & alw[gid]) == lq[:, None, :],
-                               axis=-1)
+                keep = jnp.all((lq[:, None, :] & rows(alw))
+                               == lq[:, None, :], axis=-1)
                 if tomb is not None:
                     keep = keep & ref.tombstone_mask(tomb, gid)
                 d = jnp.where(keep & valid, d, jnp.inf)
@@ -251,9 +292,11 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
             zeros, kp=kp, lmax=lmax, chunk=chunk, qtile=qtile or 8,
             metric=metric, dtype=dtype, dcols=dcols, backend=backend,
             interpret=interpret)
-    else:
+    elif span:
         (vals, pos), _ = jax.lax.scan(
-            body, init, jnp.arange(0, lmax, chunk, dtype=jnp.int32))
+            body, init, jnp.arange(0, span, chunk, dtype=jnp.int32))
+    else:   # in place over an empty arena: nothing to scan
+        vals, pos = init
     if rr is not None:
         # ---- stage 2: exact rerank of the compressed-scan shortlist ----
         # re-sort by segment position: shortlist order is (scan-distance,
@@ -264,8 +307,11 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         with jax.named_scope("rerank.rows"):
             spos = jnp.sort(pos, axis=1)
             listed = spos < lmax
-            sp = jnp.clip(starts[:, None] + spos, 0, max(R - 1, 0))
-            sgid = rows_concat[jnp.where(listed, sp, 0)]       # [Q, kp]
+            if in_place:
+                sgid = jnp.where(listed, spos, 0)              # [Q, kp]
+            else:
+                sp = jnp.clip(starts[:, None] + spos, 0, max(R - 1, 0))
+                sgid = rows_concat[jnp.where(listed, sp, 0)]   # [Q, kp]
             if backend == "pallas":
                 # shortlist rows already passed the label/tombstone filter;
                 # position-sorted means the first sum(listed) lanes are the
@@ -298,9 +344,12 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         # resolve global ids inside the traced program (empty slot -> the
         # arena-cardinality sentinel), so the executor never touches ids on
         # host and warmup covers the whole path
-        gid = jnp.where(empty, ax.shape[0],
-                        rows_concat[jnp.clip(starts[:, None] + pos, 0,
-                                             max(R - 1, 0))])
+        if in_place:
+            gid = jnp.where(empty, N, pos)
+        else:
+            gid = jnp.where(empty, N,
+                            rows_concat[jnp.clip(starts[:, None] + pos, 0,
+                                                 max(R - 1, 0))])
     return vals, pos.astype(jnp.int32), gid.astype(jnp.int32)
 
 
@@ -328,7 +377,7 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
                    chunk: int | None = None, tomb=None, dtype: str = "f32",
                    scales=None, zeros=None, rerank=None, rerank_norms=None,
                    kprime: int | None = None, fused=False,
-                   qtile: int | None = None):
+                   qtile: int | None = None, in_place: bool = False):
     """Single-dispatch segmented arena search (DESIGN.md §3).
 
     One traced program per (k, Q-bucket, lmax, metric, backend) serves every
@@ -363,6 +412,12 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
     warmup and serving resolve identical tiles, so the fused path adds no
     post-warmup cache keys.  An explicit ``chunk`` always wins (the
     parity tests sweep it).
+
+    ``in_place`` (DESIGN.md §3.10): every query's segment is the identity
+    over the arena (row list ``arange(N)``), so the unfused XLA body reads
+    contiguous arena chunks that the batch shares and never touches
+    ``rows_concat``/``starts`` — same results bit for bit.  Only where
+    :func:`in_place_capable` holds; the caller vouches for the identity.
     """
     dcols = None
     if backend == "pallas":
@@ -383,6 +438,8 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
             chunk //= 2
     if not fused:
         qtile = None  # not a knob of the unfused program: one cache key
+    if in_place:
+        rows_concat = None   # never read in place: no operand, no transfer
     before = _segmented_topk._cache_size() if _metrics.enabled() else None
     out = _segmented_topk(
         jnp.asarray(q, jnp.float32), jnp.asarray(lq, jnp.int32),
@@ -391,7 +448,8 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
         tomb, scales, zeros, rerank, rerank_norms,
         k=k, lmax=lmax, chunk=chunk or min(SEG_CHUNK, lmax), metric=metric,
         backend=backend, interpret=default_interpret(), dtype=dtype,
-        kprime=kprime, dcols=dcols, fused=fused, qtile=qtile)
+        kprime=kprime, dcols=dcols, fused=fused, qtile=qtile,
+        in_place=in_place)
     if before is not None:
         # tracing (if any) happened synchronously during the call above,
         # so the cache-size delta is already visible here
@@ -412,10 +470,12 @@ def delta_topk(q, lq, dx, dlw, dxn, tomb, count: int, *, k: int,
     """Brute-force label-filtered top-k over the streaming delta arena
     (DESIGN.md §3.6) — one traced program per (k, Q-bucket, capacity-tier).
 
-    Implemented as the SAME segmented program as the base scan, over an
-    identity row table covering the delta's full capacity tier, with every
-    query's segment being ``[0, count)`` (the append cursor arrives as a
-    traced [Q] length vector, so inserts never retrace) and the delta's own
+    Implemented as the SAME segmented program as the base scan, over the
+    identity segment covering the delta's full capacity tier — read in
+    place where the program has that path (:func:`in_place_capable`),
+    else through an identity row table — with every query's segment
+    being ``[0, count)`` (the append cursor arrives as a traced [Q]
+    length vector, so inserts never retrace) and the delta's own
     tombstone bitmap fused into the filter.  Sharing the program is what
     makes the base+delta merge bit-exact: the inner product is the same
     multiply + minor-axis reduce, so a row scores identically whether it
@@ -428,7 +488,9 @@ def delta_topk(q, lq, dx, dlw, dxn, tomb, count: int, *, k: int,
     """
     cap = dx.shape[0]
     Q = q.shape[0]
-    ident = jnp.arange(cap, dtype=jnp.int32)
+    in_place = in_place_capable(backend, resolve_fused(fused,
+                                                       backend=backend))
+    ident = None if in_place else jnp.arange(cap, dtype=jnp.int32)
     starts = jnp.zeros(Q, jnp.int32)
     lens = jnp.full((Q,), min(count, cap), jnp.int32)
     vals, pos, _ = segmented_topk(q, lq, dx, dlw, dxn, ident, starts, lens,
@@ -436,7 +498,8 @@ def delta_topk(q, lq, dx, dlw, dxn, tomb, count: int, *, k: int,
                                   backend=backend, chunk=chunk, tomb=tomb,
                                   dtype=dtype, scales=scales, zeros=zeros,
                                   rerank=rerank, rerank_norms=rerank_norms,
-                                  kprime=kprime, fused=fused, qtile=qtile)
+                                  kprime=kprime, fused=fused, qtile=qtile,
+                                  in_place=in_place)
     return vals, pos
 
 
@@ -505,6 +568,7 @@ __all__ = [
     "delta_topk",
     "filtered_topk",
     "gather_distance",
+    "in_place_capable",
     "masked_distance",
     "masked_topk_tail",
     "merge_topk",

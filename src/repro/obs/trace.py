@@ -74,6 +74,7 @@ class QueryCard:
     tombstone_density: float | None  # dead / span rows (None: no bitmap)
     recompiled: bool  # batch grew the _segmented_topk cache
     backend: str = "flat"
+    scan: str | None = None  # "in_place" | "row_table" (None: no arena scan)
 
 
 class Tracer:

@@ -115,3 +115,27 @@ def test_vmem_entry_is_the_compiler_limit(one_chip):
     compile_with_scratch(limit // 2)
     with pytest.raises(Exception, match=f"size={limit}"):
         compile_with_scratch(limit + 2**20)
+
+
+def test_in_place_root_tier_compiles_to_slices(one_chip):
+    """The benchmark cell's root tier (256 queries over the whole 1M-row
+    arena, span 2^20) on the XLA body, read in place: the arena is read
+    by contiguous slices, the only gather left is the merge's [Q, k]
+    take_along_axis, and the distance stays the f32 multiply + minor-axis
+    reduce (no dot, no convolution)."""
+    import re
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, f32, i32 = 256, jnp.float32, jnp.int32
+    text = ops._segmented_topk.lower(
+        s((q, D), f32), s((q, W), i32), s((N, D), f32), s((N, W), i32),
+        s((N,), f32), None, s((q,), i32), s((q,), i32),
+        k=K, lmax=1 << 20, chunk=ops.SEG_CHUNK, metric="l2", backend="ref",
+        interpret=False, in_place=True).compile().as_text()
+    assert not re.search(r"\b(dot|convolution)\(", text)
+    gathers = re.findall(r'= \S+ gather\(.*op_name="([^"]*)"', text)
+    assert gathers and all("scan.merge" in g for g in gathers), gathers
+    for scope in ("scan.rows", "scan.norms", "scan.label_words"):
+        assert f"{scope}/dynamic_slice" in text, scope

@@ -13,6 +13,14 @@ every vector and every call's query label sets.  The multiset is fixed
 because it fixes the selection and the length of the program's row table,
 a shape of every compiled scan: a multiset drawn from ``--seed`` would
 make each new seed compile every program afresh in set-up.
+
+Past the base rows the run's rows go on as a stream (:meth:`Dataset.rows`):
+row ``r >= n_rows`` has a vector and a label set that are a pure function
+of ``(seed, r)``, drawn in blocks of :data:`BLOCK` rows, so that any range
+regenerates without anyone keeping copies.  Its label set is a fresh draw
+of the paper's generator at the configuration's Zipf and Poisson settings,
+from the seed and not from the fixed multiset: inserted rows change the
+selection's closures as a deployment's new rows do.
 """
 from __future__ import annotations
 
@@ -85,7 +93,8 @@ def vectors(seed: int, stream: int, n: int, dim: int,
     return rng.standard_normal((n, dim), dtype=np.float32)
 
 
-BASE, QUERY, ORDER, QUERY_LABELS = 0, 2, 3, 4
+BASE, QUERY, ORDER, QUERY_LABELS, STREAM, STREAM_LABELS = 0, 2, 3, 4, 5, 6
+BLOCK = 4096          # rows of the stream drawn together
 
 
 class Dataset:
@@ -105,6 +114,39 @@ class Dataset:
         self.nonempty = [s for s in self.sets if s]
         self.vectors = vectors(seed, BASE, n, cfg["dim"])
         self.n = n
+        self._blocks: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+
+    def rows(self, lo: int, hi: int) -> tuple[np.ndarray, np.ndarray]:
+        """(vectors [hi - lo, dim] f32, label membership [hi - lo, L]
+        bool) of rows ``[lo, hi)`` of the row stream: the base rows below
+        ``n``, then the stream's blocks."""
+        if hi <= self.n:
+            return self.vectors[lo:hi], self.member[lo:hi]
+        vs, ms = [self.vectors[lo:self.n]], [self.member[lo:self.n]]
+        start = max(lo, self.n)
+        for b in range((start - self.n) // BLOCK,
+                       (hi - 1 - self.n) // BLOCK + 1):
+            v, m = self._block(b)
+            b0 = self.n + b * BLOCK
+            cut = slice(max(start, b0) - b0, min(hi, b0 + BLOCK) - b0)
+            vs.append(v[cut])
+            ms.append(m[cut])
+        return np.concatenate(vs), np.concatenate(ms)
+
+    def _block(self, b: int) -> tuple[np.ndarray, np.ndarray]:
+        """Rows ``n + b * BLOCK`` onwards, ``BLOCK`` of them; the newest
+        two blocks are kept, as inserts read the stream in order."""
+        if b not in self._blocks:
+            cfg = self.cfg
+            self._blocks = {j: blk for j, blk in self._blocks.items()
+                            if j == b - 1}
+            self._blocks[b] = (
+                vectors(self.seed, STREAM, BLOCK, cfg["dim"], b),
+                label_members(BLOCK, cfg["n_labels"], cfg["zipf_a"],
+                              cfg["mean_labels_per_row"],
+                              cfg["max_labels_per_row"],
+                              [self.seed, STREAM_LABELS, b]))
+        return self._blocks[b]
 
     def queries(self, q: int, index: int):
         """(vectors, label sets) of the ``index``-th call's ``q`` queries:

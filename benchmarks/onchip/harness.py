@@ -1,16 +1,41 @@
 """One run of one cell: set-up, the measured window, the check, the line.
 
 Everything that belongs to one cell is found by name from
-``BENCHMARK.json``: the configuration file it names, the traffic mix
-``<paths[0]>/traffic/<traffic>.json`` and each per-layer metric's reader
-``<paths[0]>/metrics/<name>.py``.  This module is the one general driver
-they feed; adding a cell, a configuration or a per-layer metric adds files
-and entries and edits nothing here.
+``BENCHMARK.json``: the configuration file it names, the engine adapter
+``<paths[0]>/engines/<engine>.py`` of the configuration's ``engine``, the
+traffic mix ``<paths[0]>/traffic/<traffic>.json`` and each per-layer
+metric's reader ``<paths[0]>/metrics/<name>.py``.  This module is the one
+general driver they feed: the closed loop, the timing, the check and the
+result line.  Adding a cell, a configuration, an engine, a traffic op or a
+per-layer metric adds files and entries and edits nothing here.
 
 A traffic mix is a closed loop of one client that repeats ``step``, a list
-of calls into the system under test, each ``{"op": "search", "queries":
-Q}``: ``search_batched`` of Q queries, their vectors and label sets fresh
-draws from the seed and the call's index (``gen.Dataset.queries``).
+of calls into the system under test, each ``{"op": <name>, ...}`` with the
+op's own parameters.  The engine adapter serves the ops; a step naming an
+op that the cell's adapter does not serve is refused.
+
+An engine adapter is a module with
+
+- ``ANNOTATIONS``: each op it serves, mapped to the profiler annotation
+  that its calls carry;
+- ``ANSWERING``: the ops whose calls answer queries (the check samples
+  them, and the per-call readers count their annotations, ``ANNOTATED``);
+- ``Engine(cfg, ds, log)``: builds the system under test from the
+  configuration and the run's rows (``gen.Dataset``).  The instance has
+  ``target``, the system under test (a test's fault receives it);
+  ``warm(traffic)``, which compiles every program the traffic reaches;
+  ``programs``, the jitted programs (name to function) whose cache growth
+  inside the window is logged as compiles; one method per op,
+  ``op(spec, index, timed) -> Call``, which prepares the call outside the
+  timing and makes the one call into the system through ``timed(fn,
+  *args)``, which times it under the op's annotation and returns ``(out,
+  seconds)``; ``place(ids)``, the row-stream index of each id an answer
+  gives, -1 where it cannot place one; and ``live()``, the rows live now
+  as one range ``(lo, hi)`` of the row stream (``gen.Dataset.rows``).
+
+A call that answers queries records its answer and the live range it was
+answered over; the check holds it against a float64 reference over
+exactly those rows.
 """
 from __future__ import annotations
 
@@ -32,7 +57,6 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 CACHE_DIR = ".jax_cache"      # under the checkout: a fixed path
 TRACE_DIR = "benchmarks/onchip/_out/trace"
-ANNOTATED = ("search_batched",)
 
 
 class CellError(ValueError):
@@ -79,6 +103,37 @@ def load_reader(bench_dir: Path, name: str) -> Callable:
     return mod.read
 
 
+def load_engine(bench_dir: Path, name: str):
+    """The engine adapter module ``engines/<name>.py``."""
+    import importlib.util
+    path = bench_dir / "engines" / f"{name}.py"
+    if not path.exists():
+        raise CellError(f"no adapter for engine {name!r} at {path}")
+    spec = importlib.util.spec_from_file_location(
+        "onchip_engine_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def answer_annotations(bench_dir: Path) -> tuple[str, ...]:
+    """The annotations of the calls that answer queries, over every engine
+    adapter under ``bench_dir/engines``."""
+    out = []
+    for path in sorted((bench_dir / "engines").glob("*.py")):
+        mod = load_engine(bench_dir, path.stem)
+        out += [mod.ANNOTATIONS[op] for op in mod.ANSWERING]
+    return tuple(dict.fromkeys(out))
+
+
+def __getattr__(name: str):
+    # ``ANNOTATED``, read by the per-call metric readers: the annotations
+    # of the calls that answer queries (:func:`answer_annotations`)
+    if name == "ANNOTATED":
+        return answer_annotations(HERE)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
 def tpu_devices(chips: int, log: Callable[[str], None]):
     """The first ``chips`` TPU devices, or None (with the reason logged)
     when JAX finds no TPU or too few."""
@@ -104,35 +159,43 @@ class Call:
 
 
 class Driver:
-    """Drives one engine with one traffic mix."""
+    """Drives one engine adapter (``engine``, an ``Engine`` of
+    ``bench_dir/engines/<cfg["engine"]>.py``) with one traffic mix."""
 
-    def __init__(self, cfg: dict, traffic: dict, ds: gen.Dataset, target,
-                 log: Callable[[str], None]):
+    def __init__(self, cfg: dict, traffic: dict, ds: gen.Dataset, engine,
+                 log: Callable[[str], None], bench_dir: Path = HERE):
         self.cfg, self.traffic, self.ds = cfg, traffic, ds
-        self.target, self.log = target, log
-        self.k = cfg["k"]
+        self.engine, self.log = engine, log
+        served = load_engine(bench_dir, cfg["engine"]).ANNOTATIONS
         ops = {s["op"] for s in traffic["step"]}
-        if ops != {"search"}:
-            raise CellError(f"unknown traffic ops {sorted(ops - {'search'})}")
-        self.q_max = max(s["queries"] for s in traffic["step"])
+        if not ops <= set(served):
+            raise CellError(f"engine {cfg['engine']!r} serves no traffic "
+                            f"ops {sorted(ops - set(served))}")
+        self.served = served
+        # the annotations of the traffic's calls, which the trace reads
+        self.annotations = tuple(dict.fromkeys(served[s["op"]]
+                                               for s in traffic["step"]))
         self.n_calls = 0
         self.trace_cards = False
 
     def call(self, spec: dict) -> Call:
-        """One search call of ``spec["queries"]`` fresh queries."""
-        q, index = spec["queries"], self.n_calls
+        """One call of the op ``spec["op"]``; an answer's ids are placed in
+        the row stream and it records the live range it was answered
+        over."""
+        op, index = spec["op"], self.n_calls
         self.n_calls += 1
-        qv, qls = self.ds.queries(q, index)
         n_cards = self._cards_len()
-        t0 = time.perf_counter()
-        with jax_annotation("search_batched"):
-            d, ids = self.target.search_batched(qv, qls, self.k)
-        dt = time.perf_counter() - t0
-        ids = np.asarray(ids, np.int64)
-        ids = np.where(ids < self.ds.n, ids, -1)   # the sentinel: empty
-        c = Call("search", dt, queries=q,
-                 answer={"qv": qv, "qls": qls, "ids": ids,
-                         "d": np.asarray(d)})
+
+        def timed(fn, *args):
+            t0 = time.perf_counter()
+            with jax_annotation(self.served[op]):
+                out = fn(*args)
+            return out, time.perf_counter() - t0
+
+        c = getattr(self.engine, op)(spec, index, timed)
+        if c.answer is not None:
+            c.answer["ids"] = self.engine.place(c.answer["ids"])
+            c.answer["live"] = self.engine.live()
         if self.trace_cards:
             c.cards = self._cards_since(n_cards)
         return c
@@ -151,30 +214,14 @@ class Driver:
                  c.q_bucket) for c in self._tracer().cards[n:]]
 
     def warm(self) -> None:
-        """Compile every program the traffic can reach: the engine's own
-        warm-up over the Q-bucket ladder up to the largest call, as each
-        call's fresh label sets spread its queries over the span tiers in
-        numbers that change from call to call."""
-        out = self.target.warmup_serving([self.k], min_bucket=1,
-                                         max_batch=self.q_max)
-        self.log(f"[warm] {out['programs']} programs over the Q-bucket "
-                 f"ladder up to {self.q_max}: {out['seconds']:.1f}s")
+        """Compile every program the traffic can reach (the adapter's
+        ``warm``)."""
+        self.engine.warm(self.traffic)
 
 
 def jax_annotation(name: str):
     import jax
     return jax.profiler.TraceAnnotation(name)
-
-
-def build_target(cfg: dict, ds: gen.Dataset):
-    """The system under test as the configuration states it."""
-    from repro.core.engine import LabelHybridEngine
-    if cfg["engine"] != "static":
-        raise CellError(f"unknown engine {cfg['engine']!r}")
-    return LabelHybridEngine.build(
-        ds.vectors, ds.sets, mode=cfg["selection"], c=cfg["c"],
-        backend=cfg["index_backend"], metric=cfg["metric"],
-        storage=cfg["storage"])
 
 
 def percentile(values, q: float) -> float:
@@ -185,39 +232,45 @@ def percentile(values, q: float) -> float:
 
 def check_answers(calls: list[Call], ds: gen.Dataset, traffic: dict,
                   seed: int, k: int, log, answer_fn=None) -> dict:
-    """Hold a sample of the window's answers, drawn from the seed, against
-    the reference (``reference.compare``).  ``answer_fn``, called as
-    ``reference.control_topk`` is, puts other answers in the program's
-    place (the control)."""
+    """Hold a sample of the window's answers, drawn from the seed among
+    the calls that answer queries, against the reference over the rows
+    live when each was answered (``reference.compare``): a row inserted
+    before the call must be found, a row deleted before it never comes
+    back.  ``answer_fn``, called as ``reference.control_topk`` is, puts
+    other answers in the program's place (the control)."""
     check = traffic["check"]
+    answered = [c for c in calls if c.answer is not None]
     rng = np.random.default_rng([seed, 9])
-    n_pick = min(check["searches"], len(calls))
-    picked = set(rng.choice(len(calls), size=n_pick, replace=False)
+    n_pick = min(check["searches"], len(answered))
+    picked = set(rng.choice(len(answered), size=n_pick, replace=False)
                  .tolist()) if n_pick else set()
-    picked.add(len(calls) - 1)          # and the last call
-    ref = reference.Reference(ds.vectors, ds.member)
+    picked.add(len(answered) - 1)       # and the last one
+    # one reference over the row stream up to the newest row any picked
+    # call saw; ids are row-stream indices, each call's live range a slice
+    x, member = ds.rows(0, max(answered[i].answer["live"][1]
+                               for i in picked))
+    ref = reference.Reference(x, member)
     parts = []
     n_queries = 0
     for i in sorted(picked):
-        c = calls[i]
+        c = answered[i]
         a = c.answer
+        lo, hi = a["live"]
         sel = np.sort(rng.choice(c.queries, size=min(check["queries"],
                                                      c.queries),
                                  replace=False))
         qv = a["qv"][sel]
-        qmasks = gen.as_member([a["qls"][j] for j in sel],
-                               ds.member.shape[1])
-        want_i, want_d = ref.topk(qv, qmasks, k, 0, ds.n)
+        qmasks = gen.as_member([a["qls"][j] for j in sel], member.shape[1])
+        want_i, want_d = ref.topk(qv, qmasks, k, lo, hi)
         if answer_fn is None:
             got_i, got_d = a["ids"][sel], a["d"][sel]
         else:
-            got_i, got_d = answer_fn(ds.vectors, ref.member, qv, qmasks, k,
-                                     0, ds.n)
-        parts.append(reference.compare(ref, qv, qmasks, got_i, got_d, 0,
-                                       ds.n, want_i, want_d))
+            got_i, got_d = answer_fn(x, ref.member, qv, qmasks, k, lo, hi)
+        parts.append(reference.compare(ref, qv, qmasks, got_i, got_d, lo,
+                                       hi, want_i, want_d))
         n_queries += len(sel)
     numbers = reference.merge(parts)
-    log(f"[check] {n_queries} queries of {len(picked)} of {len(calls)} "
+    log(f"[check] {n_queries} queries of {len(picked)} of {len(answered)} "
         f"calls held against the float64 reference")
     return numbers
 
@@ -243,21 +296,23 @@ class Run:
         log(f"[data] {self.ds.n:,} x {cfg['dim']} rows, "
             f"{cfg['n_labels']} labels: "
             f"{time.perf_counter() - t_start:.1f}s since start")
+        adapter = load_engine(self.cell["bench_dir"], cfg["engine"])
         t = time.perf_counter()
-        target = build_target(cfg, self.ds)
+        engine = adapter.Engine(cfg, self.ds, log)
         log(f"[build] {cfg['engine']} engine: {time.perf_counter() - t:.1f}s")
-        self.drv = Driver(cfg, self.cell["traffic"], self.ds, target, log)
+        self.drv = Driver(cfg, self.cell["traffic"], self.ds, engine, log,
+                          self.cell["bench_dir"])
         self.drv.warm()
         if self.fault is not None:
-            self.fault(target)
+            self.fault(engine.target)
 
     def window(self, seconds: float, trace: bool = False) -> None:
         """Drive the traffic for ``seconds``, ending on a completed call."""
         import jax
-        from repro.kernels import ops as program_ops
         from repro.obs import trace as program_trace
         drv, steps = self.drv, self.cell["traffic"]["step"]
-        seg_before = program_ops._segmented_topk._cache_size()
+        programs = drv.engine.programs
+        before = sum(f._cache_size() for f in programs.values())
         self.trace = trace
         if trace:
             drv.trace_cards = True
@@ -287,15 +342,15 @@ class Run:
         self.calls = calls
         self.peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                         for d in self.devices)
-        seg_new = program_ops._segmented_topk._cache_size() - seg_before
+        compiles = sum(f._cache_size() for f in programs.values()) - before
         self.log(f"[window] {self.window_s:.2f}s: {len(calls)} calls, "
                  f"{sum(c.queries for c in calls)} queries")
-        self.log(f"[window] compiles inside the window: {seg_new} "
-                 f"(_segmented_topk cache growth)")
+        self.log(f"[window] compiles inside the window: {compiles} "
+                 f"({', '.join(programs)} cache growth)")
 
     def release(self) -> None:
         """Drop the program's state before the reference runs."""
-        self.drv.target = None
+        self.drv.engine = None
 
     def check(self, answer_fn=None) -> dict:
         return check_answers(self.calls, self.ds, self.cell["traffic"],
@@ -342,7 +397,8 @@ class Run:
     def _per_layer(self, device: dict) -> dict:
         import trace_reduce
         cell = self.cell
-        red = trace_reduce.reduce_dir(cell["root"] / TRACE_DIR, ANNOTATED,
+        red = trace_reduce.reduce_dir(cell["root"] / TRACE_DIR,
+                                      self.drv.annotations,
                                       n_devices=len(self.devices))
         ctx = MetricContext(cfg=cell["config"], calls=self.calls,
                             spans=self.spans, trace=red,

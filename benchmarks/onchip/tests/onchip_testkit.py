@@ -18,11 +18,12 @@ STATIC = "eli1m-static.paper-mix"
 
 def tiny_root(tmp: Path, n_rows: int = 2000, dim: int = 128) -> Path:
     """A checkout-shaped directory holding the benchmark's own
-    ``BENCHMARK.json``, configurations, traffic mixes and metric readers,
-    cut to ``n_rows`` rows; the program is the repository's ``src``."""
+    ``BENCHMARK.json``, configurations, engine adapters, traffic mixes and
+    metric readers, cut to ``n_rows`` rows; the program is the
+    repository's ``src``."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     dst = tmp / bench["paths"][0]
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "engines", "traffic", "metrics"):
         shutil.copytree(ONCHIP / sub, dst / sub)
     (tmp / "src").symlink_to(REPO / "src")
     for c in bench["configs"]:

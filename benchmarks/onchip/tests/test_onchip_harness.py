@@ -93,6 +93,33 @@ def test_a_traffic_op_it_does_not_know_is_refused(tmp_path):
         harness.Driver(cell["config"], traffic, None, None, print)
 
 
+def test_harness_names_no_engine_and_no_op(tmp_path):
+    import ast
+    names = set()
+    for path in (kit.ONCHIP / "engines").glob("*.py"):
+        mod = harness.load_engine(kit.ONCHIP, path.stem)
+        names |= {path.stem} | set(mod.ANNOTATIONS) | set(
+            mod.ANNOTATIONS.values())
+    assert {"static", "search", "search_batched"} <= names
+    tree = ast.parse((kit.ONCHIP / "harness.py").read_text())
+    docs = {id(node.body[0].value) for node in ast.walk(tree)
+            if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef))
+            and ast.get_docstring(node) is not None}
+    literals = {node.value for node in ast.walk(tree)
+                if isinstance(node, ast.Constant)
+                and isinstance(node.value, str) and id(node) not in docs}
+    assert not literals & names
+    assert harness.ANNOTATED == ("search_batched",)
+    # an engine is found by its adapter's file, and one without is refused
+    root = kit.tiny_root(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    path = root / bench["configs"][0]["file"]
+    cfg = json.loads(path.read_text())
+    path.write_text(json.dumps(dict(cfg, engine="no-such-engine")))
+    with pytest.raises(harness.CellError, match="no adapter"):
+        kit.run(root, kit.STATIC)
+
+
 def test_nothing_compiles_inside_the_window(tmp_path):
     lines = []
     out = kit.run(kit.tiny_root(tmp_path), kit.STATIC, seconds=2.0,

@@ -191,9 +191,8 @@ def record_search_telemetry(engine, routed, qmasks, k, n_queries, clock, *,
             else:
                 _M_UNSEEN.inc(count)
         if tracing:
-            seg = engine.segments.get(skey)
-            span_tier = (pow2_bucket(seg[1])
-                         if seg is not None and arena is not None else None)
+            span_tier = (engine.seg_tier.get(skey)
+                         if arena is not None else None)
             if tier_bucket is not None and span_tier is not None:
                 q_bucket = tier_bucket.get((span_tier, skey in in_place))
             else:
@@ -265,6 +264,50 @@ class EngineStats:
     tombstone_nbytes: int = 0    # packed delete bitmap(s)
 
 
+def held_capacity(cap: int, need: int, headroom: float) -> int:
+    """The length a held buffer keeps for ``need`` entries: ``cap`` while
+    they fit, else ``need`` plus ``headroom`` of it, in whole bytes of a
+    tombstone bitmap (8 rows) — so a buffer starts with headroom over its
+    first length, grows by that fraction when outgrown, never shrinks."""
+    if cap and need <= cap:
+        return cap
+    return max(8, -(-int(np.ceil(need * (1.0 + headroom))) // 8) * 8)
+
+
+@dataclasses.dataclass
+class ShapeReserve:
+    """Device shapes a streaming engine holds across compactions
+    (DESIGN.md §3.6).  Every compiled scan program is keyed on the
+    arena's row count, the row table's length and its span tier, and a
+    compaction with freshly labelled rows moves all three.  Held, the
+    arena and the row table keep a capacity with headroom
+    (:func:`held_capacity`), padded with rows and entries no segment
+    addresses, and each selected key keeps its span tier while its
+    segment fits in it — so a compaction compiles nothing while the
+    shapes hold, and results are those of the unpadded engine bit for
+    bit."""
+    ROW_HEADROOM = 1 / 64       # row table; a segment's growth margin
+    ARENA_HEADROOM = 1 / 512    # arena rows: ~1 MB at 1M x 128 f32
+
+    rows: int = 0               # device row table length
+    arena: int = 0              # arena row capacity
+    tiers: dict = dataclasses.field(default_factory=dict)   # key -> tier
+
+    def hold_rows(self, need: int) -> int:
+        self.rows = held_capacity(self.rows, need, self.ROW_HEADROOM)
+        return self.rows
+
+    def hold_arena(self, need: int) -> int:
+        self.arena = held_capacity(self.arena, need, self.ARENA_HEADROOM)
+        return self.arena
+
+    def hold_tier(self, key: tuple[int, ...], length: int) -> int:
+        tier = self.tiers.get(key)
+        if tier is None or length > tier:
+            tier = self.tiers[key] = pow2_bucket(length)
+        return tier
+
+
 class LabelHybridEngine:
     """Build-once, search-many ELI engine over a pluggable index backend."""
 
@@ -301,6 +344,9 @@ class LabelHybridEngine:
         self.indexes: dict[tuple[int, ...], object] = {}
         self.rows: dict[tuple[int, ...], np.ndarray] = {}
         self.segments: dict[tuple[int, ...], tuple[int, int]] = {}
+        # held device shapes (streaming, :meth:`hold_shapes`); None: each
+        # buffer is exactly as long as its contents
+        self.reserve: ShapeReserve | None = None
         t0 = time.perf_counter()
         self.rebase(vectors, label_sets, table, selection)
         self._build_seconds = time.perf_counter() - t0
@@ -391,6 +437,12 @@ class LabelHybridEngine:
             off += rows.size
         self.rows_concat = (np.concatenate(parts) if parts
                             else np.zeros(0, np.int32))
+        # the span tier each key's launches use: its segment's
+        # power-of-two bucket, or, with held shapes, the tier it held
+        self.seg_tier = {
+            key: (pow2_bucket(length) if self.reserve is None
+                  else self.reserve.hold_tier(key, length))
+            for key, (_, length) in self.segments.items()}
         # segments the unfused XLA scan reads in place (DESIGN.md §3.10):
         # exactly those whose row list is the identity arange(n) — the
         # root, whether built here or handed over through rows_hint
@@ -403,8 +455,13 @@ class LabelHybridEngine:
                 if np.array_equal(rows, ident))
         # the device copy of the CSR table feeds the segmented kernel and
         # the views; private-storage backends never read it on device, so
-        # they skip the upload (and its HBM) entirely
-        self._rows_concat_dev = (jnp.asarray(self.rows_concat)
+        # they skip the upload (and its HBM) entirely.  Held, it is padded
+        # to its capacity with entries no segment addresses
+        table = self.rows_concat
+        if self.reserve is not None:
+            table = np.zeros(self.reserve.hold_rows(table.size), np.int32)
+            table[:self.rows_concat.size] = self.rows_concat
+        self._rows_concat_dev = (jnp.asarray(table)
                                  if self._arena_native else None)
 
         if self._arena_native and self.arena is not None:
@@ -660,7 +717,7 @@ class LabelHybridEngine:
                         self.arena.norms, self._rows_concat_dev, starts,
                         lens, k=k, lmax=lmax, metric=self.metric,
                         backend=self._seg_backend, fused=self._seg_fused,
-                        in_place=in_place, **self.arena.tier_kwargs())
+                        in_place=in_place, **self.scan_kwargs())
                 clock.lap("launch")
                 # global ids resolved inside the traced program (sentinel n
                 # included): no host remap, and warmup covers the full path
@@ -743,7 +800,7 @@ class LabelHybridEngine:
         tiers: dict[tuple[int, bool], list[int]] = {}
         with _trace.span("search.pack"):
             for qi, key in enumerate(routed):
-                tiers.setdefault((pow2_bucket(self.segments[key][1]),
+                tiers.setdefault((self.seg_tier[key],
                                   key in self.in_place_keys), []).append(qi)
         for lmax, in_place in sorted(tiers):
             with _trace.span("search.pack", lmax=lmax):
@@ -847,7 +904,7 @@ class LabelHybridEngine:
                             self._rows_concat_dev, zero, zero, k=k,
                             lmax=lmax, metric=self.metric,
                             backend=self._seg_backend, fused=self._seg_fused,
-                            in_place=in_place, **self.arena.tier_kwargs())
+                            in_place=in_place, **self.scan_kwargs())
                         outs.append(vals)
                 else:
                     for index in self.indexes.values():
@@ -870,9 +927,41 @@ class LabelHybridEngine:
 
     def span_tiers(self) -> list[tuple[int, bool]]:
         """The (span tier, in place) launches ``arena_tier_batches`` can
-        yield for this selection — what the warm-ups compile."""
-        return sorted({(pow2_bucket(length), key in self.in_place_keys)
-                       for key, (_, length) in self.segments.items()})
+        yield for this selection — what the warm-ups compile.  With held
+        shapes, a segment within its growth margin
+        (``ShapeReserve.ROW_HEADROOM``, and never past the arena's rows)
+        of its tier's top can reach the tier above, which is listed too."""
+        tiers = {(tier, key in self.in_place_keys)
+                 for key, tier in self.seg_tier.items()}
+        if self.reserve is not None:
+            for key, (_, length) in self.segments.items():
+                reach = min(int(np.ceil(length
+                                        * (1 + ShapeReserve.ROW_HEADROOM))),
+                            self.arena.n)
+                if reach > self.seg_tier[key]:
+                    tiers.add((pow2_bucket(reach), key in self.in_place_keys))
+        return sorted(tiers)
+
+    def scan_kwargs(self) -> dict:
+        """The arena operands of every base-scan launch besides its rows:
+        the storage tiers, and, where the arena is held past the dataset,
+        the dataset's cardinality as the empty-slot id."""
+        kw = self.arena.tier_kwargs()
+        if self.reserve is not None:
+            kw["n_rows"] = np.int32(len(self.label_sets))
+        return kw
+
+    def hold_shapes(self) -> None:
+        """Hold the device shapes across rebases (:class:`ShapeReserve`):
+        pad the arena and the row table to capacities with headroom and
+        keep each key's span tier from here on.  Arena-native engines
+        only; a second call changes nothing."""
+        if self.reserve is not None or self.arena is None:
+            return
+        self.reserve = ShapeReserve()
+        self.arena = self.arena.padded(
+            self.reserve.hold_arena(len(self.label_sets)))
+        self.apply_selection(self.selection)
 
     def warmup_serving(self, ks: Sequence[int], min_bucket: int,
                        max_batch: int, **search_params) -> dict:

@@ -154,6 +154,11 @@ class StreamingEngine:
             metric=engine.metric, storage=engine.storage,
             **engine.backend_params)
         self.compaction_log: list[dict] = []
+        if self.lazy:
+            # compactions move the arena's rows, the row table's length
+            # and segments' span tiers, each a shape of every scan
+            # program: held, a compaction compiles nothing
+            engine.hold_shapes()
         self._reset_staging()
 
     # -- construction ---------------------------------------------------------
@@ -254,9 +259,15 @@ class StreamingEngine:
         the pending state is compacted FIRST (see ``compaction_log`` for
         the renumbering of earlier ids) and the batch lands in the fresh
         delta — the ids returned are therefore always valid at return.
+        A ``stream.insert`` span, holding the ``stream.compact`` of such a
+        flush and the ``stream.delta_append``.
         """
-        _t0 = (time.perf_counter()
-               if _metrics.enabled() or _trace.enabled() else 0.0)
+        with _trace.span("stream.insert"):
+            return self._insert(vectors, label_sets)
+
+    def _insert(self, vectors: np.ndarray,
+                label_sets: Sequence[tuple[int, ...]]) -> np.ndarray:
+        _t0 = time.perf_counter() if _metrics.enabled() else 0.0
         vectors = np.ascontiguousarray(vectors, dtype=np.float32)
         if vectors.ndim != 2 or vectors.shape[1] != self.base.vectors.shape[1]:
             raise ValueError(f"expected [m, {self.base.vectors.shape[1]}] "
@@ -279,7 +290,10 @@ class StreamingEngine:
         # (typed CapacityError at the max_delta_capacity ceiling), and a
         # failed insert must leave the engine bit-for-bit unchanged — no
         # half-staged host parts, no advanced cursor
-        new_delta = self.delta.appended(vectors, lw) if self.lazy else None
+        new_delta = None
+        if self.lazy:
+            with _trace.span("stream.delta_append"):
+                new_delta = self.delta.appended(vectors, lw)
         self._delta_vec_parts.append(vectors)
         self._delta_lw_parts.append(lw)
         self._delta_ls.extend(label_sets)
@@ -320,9 +334,13 @@ class StreamingEngine:
         per-selected-key bitmaps, re-derived at the next search —
         O(Σ|I|/8) host bytes, never O(build).  Staged-delta deletes ride
         the fold their insert already forced.  May trigger automatic
-        compaction."""
-        _t0 = (time.perf_counter()
-               if _metrics.enabled() or _trace.enabled() else 0.0)
+        compaction.  A ``stream.delete`` span, holding the
+        ``stream.tomb_upload`` and any ``stream.compact``."""
+        with _trace.span("stream.delete"):
+            return self._delete(ids)
+
+    def _delete(self, ids) -> int:
+        _t0 = time.perf_counter() if _metrics.enabled() else 0.0
         ids = np.unique(np.asarray(ids, dtype=np.int64).ravel())
         if ids.size == 0:
             return 0
@@ -338,12 +356,14 @@ class StreamingEngine:
         self._base_dead[base_ids] = True
         self._delta_dead[delta_slots] = True
         if self.lazy:
-            if base_ids.size:
-                self.base.arena = self.base.arena.with_tombstones(
-                    self._base_dead)
-                self._has_base_tombs = True
-            if delta_slots.size:
-                self.delta = self.delta.with_tombstones(self._delta_dead)
+            with _trace.span("stream.tomb_upload"):
+                if base_ids.size:
+                    self.base.arena = self.base.arena.with_tombstones(
+                        self._base_dead)
+                    self._has_base_tombs = True
+                if delta_slots.size:
+                    self.delta = self.delta.with_tombstones(
+                        self._delta_dead)
         elif base_ids.size:
             if self.lazy_deletes_active:
                 self._has_base_tombs = True
@@ -408,7 +428,13 @@ class StreamingEngine:
         """Compact now: fold live delta rows in, drop tombstoned rows,
         renumber survivors (report carries the ``id_map``), optionally
         piggyback a drift-triggered reselect.  Returns the report (also
-        appended to ``compaction_log``)."""
+        appended to ``compaction_log``).  A ``stream.compact`` span: the
+        survivors, the incremental GroupTable, the fold, the upload and
+        the rebase."""
+        with _trace.span("stream.compact"):
+            return self._flush()
+
+    def _flush(self) -> dict:
         t0 = time.perf_counter()
         eng = self.base
         alive_base, alive_delta, id_map, new_ls = self._survivors()
@@ -449,9 +475,6 @@ class StreamingEngine:
             _M_LIVE.set(self.sentinel - dead)
             _M_TOMB.set(dead)
             _M_DELTA.set(self._n_inserted)
-        if _trace.enabled():
-            _trace.get_tracer().complete(
-                "stream." + op, t0, time.perf_counter(), rows=rows)
 
     def _piggyback_selection(self, table: GroupTable) -> EISResult | None:
         """Drift-triggered weighted reselect, evaluated only when a
@@ -515,27 +538,25 @@ class StreamingEngine:
             rows_hint[key] = as_row_ids(r, table.n)
 
         # fold the arena from the host mirrors (every buffer already lives
-        # there) and carry the version forward.  A device-side gather fold
-        # would avoid the re-upload, but its XLA programs are keyed on the
-        # survivor count — a shape that essentially never repeats — so
-        # every flush would pay compilation instead (measured dominant on
-        # CPU; a padded-shape device fold is the recorded TPU follow-up,
-        # ROADMAP)
+        # there) into buffers of the held capacity — the survivors, then
+        # zero pad rows — and carry the version forward.  A device-side
+        # gather fold would avoid the re-upload (a padded-shape device fold
+        # is the recorded TPU follow-up, ROADMAP D7)
         import dataclasses as _dc
 
-        dv = (np.concatenate(self._delta_vec_parts)[alive_delta]
-              if self._n_inserted else
-              np.zeros((0, eng.vectors.shape[1]), np.float32))
-        dlw = (np.concatenate(self._delta_lw_parts)[alive_delta]
-               if self._n_inserted else
-               np.zeros((0, eng.label_words.shape[1]), np.int32))
-        new_vecs = np.concatenate([eng.vectors[alive_base], dv])
-        new_lw = np.concatenate([eng.label_words[alive_base], dlw])
-        arena = _dc.replace(Arena.from_host(new_vecs, new_lw,
-                                            storage=eng.storage),
+        nb, n = int(alive_base.sum()), len(new_ls)
+        cap = eng.reserve.hold_arena(n)
+        vecs = np.zeros((cap, eng.vectors.shape[1]), np.float32)
+        lws = np.zeros((cap, eng.label_words.shape[1]), np.int32)
+        np.compress(alive_base, eng.vectors, axis=0, out=vecs[:nb])
+        np.compress(alive_base, eng.label_words, axis=0, out=lws[:nb])
+        if self._n_inserted:
+            vecs[nb:n] = np.concatenate(self._delta_vec_parts)[alive_delta]
+            lws[nb:n] = np.concatenate(self._delta_lw_parts)[alive_delta]
+        arena = _dc.replace(Arena.from_host(vecs, lws, storage=eng.storage),
                             version=eng.arena.version + 1)
-        eng.rebase(new_vecs, new_ls, table, selection, arena=arena,
-                   label_words=new_lw, rows_hint=rows_hint)
+        eng.rebase(vecs[:n], new_ls, table, selection, arena=arena,
+                   label_words=lws[:n], rows_hint=rows_hint)
         return reselected
 
     def _compact_private(self, alive_base, alive_delta, new_ls) -> bool:
@@ -647,7 +668,7 @@ class StreamingEngine:
                     k=k, lmax=lmax, metric=eng.metric,
                     backend=eng._seg_backend, tomb=tomb,
                     fused=eng._seg_fused, in_place=in_place,
-                    **eng.arena.tier_kwargs())
+                    **eng.scan_kwargs())
                 idx = np.full(bvals.shape[0], qb, np.int32)
                 idx[:g] = qids                  # pad lanes scatter out of
                 base_v, base_g = _kernel_ops.scatter_topk_rows(
@@ -734,19 +755,23 @@ class StreamingEngine:
                             k=k, lmax=lmax, metric=eng.metric,
                             backend=eng._seg_backend, tomb=tomb,
                             fused=eng._seg_fused, in_place=in_place,
-                            **eng.arena.tier_kwargs())
+                            **eng.scan_kwargs())
                         outs.append(bvals)
                 mv, _ = _kernel_ops.merge_topk(
                     bvals, bgid, dvals, dslot, len(eng.label_sets),
                     self.sentinel, k=k)
                 outs.append(mv)
-                # the assembly scatter for a tier whose group fills the
-                # whole bucket (smaller tiers trace on first contact)
-                sv, _ = _kernel_ops.scatter_topk_rows(
-                    jnp.full((bucket, k), jnp.inf, jnp.float32),
-                    jnp.full((bucket, k), 0, jnp.int32),
-                    zero, dvals, dslot)
-                outs.append(sv)
+                # the assembly scatter of each tier bucket a batch of this
+                # bucket can hold: a tier's group is any part of the batch
+                for tb in sorted({pow2_bucket(t) for t in buckets}):
+                    if tb <= bucket:
+                        sv, _ = _kernel_ops.scatter_topk_rows(
+                            jnp.full((bucket, k), jnp.inf, jnp.float32),
+                            jnp.full((bucket, k), 0, jnp.int32),
+                            jnp.zeros(tb, jnp.int32),
+                            jnp.full((tb, k), jnp.inf, jnp.float32),
+                            jnp.zeros((tb, k), jnp.int32))
+                        outs.append(sv)
         for o in outs:
             jax.block_until_ready(jnp.asarray(o))
         return {"seconds": time.perf_counter() - t0, "programs": len(outs)}

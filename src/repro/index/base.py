@@ -338,8 +338,36 @@ class Arena:
         return dataclasses.replace(self, tombstones=jnp.asarray(packed),
                                    version=self.version + 1)
 
+    def padded(self, rows: int) -> "Arena":
+        """This arena grown to ``rows`` rows (device-side; never shrunk).
+        Pad rows are what :meth:`from_host` makes of zero rows (codes 0,
+        int8 scale 1, zero 0, norm 0, no labels, not tombstoned), and no
+        scan addresses them: a segment lists real rows only, and the
+        in-place read masks positions past its segment's length."""
+        import jax.numpy as jnp
+        extra = rows - self.n
+        if extra <= 0:
+            return self
+
+        def pad(buf, value=0):
+            if buf is None:
+                return None
+            return jnp.concatenate(
+                [buf, jnp.full((extra,) + buf.shape[1:], value, buf.dtype)])
+
+        tombs = jnp.zeros(tombstone_bytes(rows), jnp.uint8)
+        if self.tombstones is not None:
+            tombs = tombs.at[:self.tombstones.shape[0]].set(self.tombstones)
+        return dataclasses.replace(
+            self, vectors=pad(self.vectors),
+            label_words=pad(self.label_words), norms=pad(self.norms),
+            tombstones=tombs,
+            scales=pad(self.scales, 1.0), zeros=pad(self.zeros),
+            rerank=pad(self.rerank), rerank_norms=pad(self.rerank_norms))
+
     @property
     def n(self) -> int:
+        """Rows held, pad rows of a padded arena included."""
         return self.vectors.shape[0]
 
     @property

@@ -8,6 +8,7 @@ All functions take *unpadded* arrays and return unpadded results.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -142,14 +143,15 @@ def in_place_capable(backend: str, fused: bool) -> bool:
 @functools.partial(jax.jit, static_argnames=("k", "lmax", "chunk", "metric",
                                              "backend", "interpret", "dtype",
                                              "kprime", "dcols", "fused",
-                                             "qtile", "in_place"))
+                                             "qtile", "in_place", "scope"))
 def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
-                    tomb=None, scales=None, zeros=None, rr=None, rrn=None, *,
+                    tomb=None, scales=None, zeros=None, rr=None, rrn=None,
+                    n_rows=None, *,
                     k: int, lmax: int, chunk: int, metric: str, backend: str,
                     interpret: bool, dtype: str = "f32",
                     kprime: int | None = None, dcols: int | None = None,
                     fused: bool = False, qtile: int | None = None,
-                    in_place: bool = False):
+                    in_place: bool = False, scope: str | None = None):
     """Chunked segmented arena top-k — bit-identical to the unchunked
     oracle ``ref.segmented_filtered_topk``.
 
@@ -189,7 +191,29 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
     the row-table body's — same bits.  The span is ``min(lmax, N)``; the
     last chunk's slice start is clamped into the arena and the rows it
     re-reads below the chunk's own start are masked out.
+
+    ``n_rows`` (optional traced scalar): the empty-slot id of ``gid``,
+    where the arena holds pad rows past its dataset (a streaming engine's
+    held capacity, ``core.engine.ShapeReserve``); ``None`` keeps the
+    arena's row count, ``N``.  ``scope``: a name every stage of the
+    program nests under (the delta scan's ``delta.scan``), so that a
+    profile tells its device ops from the base scan's; ``None`` adds none.
     """
+    with (contextlib.nullcontext() if scope is None
+          else jax.named_scope(scope)):
+        return _segmented_scan(
+            q, lq, ax, alw, axn, rows_concat, starts, lens, tomb, scales,
+            zeros, rr, rrn, n_rows, k=k, lmax=lmax, chunk=chunk,
+            metric=metric, backend=backend, interpret=interpret,
+            dtype=dtype, kprime=kprime, dcols=dcols, fused=fused,
+            qtile=qtile, in_place=in_place)
+
+
+def _segmented_scan(q, lq, ax, alw, axn, rows_concat, starts, lens, tomb,
+                    scales, zeros, rr, rrn, n_rows, *, k, lmax, chunk,
+                    metric, backend, interpret, dtype, kprime, dcols, fused,
+                    qtile, in_place):
+    """The body of :func:`_segmented_topk`, traced inside its jit."""
     Q = q.shape[0]
     if lmax % chunk:
         raise ValueError(f"chunk {chunk} must divide lmax {lmax}")
@@ -342,12 +366,13 @@ def _segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens,
         pos = jnp.where(empty, lmax, pos)
         vals = jnp.where(empty, jnp.float32(jnp.inf), vals)
         # resolve global ids inside the traced program (empty slot -> the
-        # arena-cardinality sentinel), so the executor never touches ids on
-        # host and warmup covers the whole path
+        # dataset-cardinality sentinel), so the executor never touches ids
+        # on host and warmup covers the whole path
+        sentinel = N if n_rows is None else n_rows
         if in_place:
-            gid = jnp.where(empty, N, pos)
+            gid = jnp.where(empty, sentinel, pos)
         else:
-            gid = jnp.where(empty, N,
+            gid = jnp.where(empty, sentinel,
                             rows_concat[jnp.clip(starts[:, None] + pos, 0,
                                                  max(R - 1, 0))])
     return vals, pos.astype(jnp.int32), gid.astype(jnp.int32)
@@ -377,7 +402,8 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
                    chunk: int | None = None, tomb=None, dtype: str = "f32",
                    scales=None, zeros=None, rerank=None, rerank_norms=None,
                    kprime: int | None = None, fused=False,
-                   qtile: int | None = None, in_place: bool = False):
+                   qtile: int | None = None, in_place: bool = False,
+                   n_rows=None, scope: str | None = None):
     """Single-dispatch segmented arena search (DESIGN.md §3).
 
     One traced program per (k, Q-bucket, lmax, metric, backend) serves every
@@ -418,6 +444,11 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
     contiguous arena chunks that the batch shares and never touches
     ``rows_concat``/``starts`` — same results bit for bit.  Only where
     :func:`in_place_capable` holds; the caller vouches for the identity.
+
+    ``n_rows``: the empty-slot id of ``gid`` where the arena is padded
+    past its dataset (traced, so a changing count adds no dispatch key);
+    ``scope``: a name the program's stages nest under in a profile
+    (:func:`_segmented_topk`).
     """
     dcols = None
     if backend == "pallas":
@@ -445,11 +476,11 @@ def segmented_topk(q, lq, ax, alw, axn, rows_concat, starts, lens, *, k: int,
         jnp.asarray(q, jnp.float32), jnp.asarray(lq, jnp.int32),
         ax, alw, axn, rows_concat,
         jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32),
-        tomb, scales, zeros, rerank, rerank_norms,
+        tomb, scales, zeros, rerank, rerank_norms, n_rows,
         k=k, lmax=lmax, chunk=chunk or min(SEG_CHUNK, lmax), metric=metric,
         backend=backend, interpret=default_interpret(), dtype=dtype,
         kprime=kprime, dcols=dcols, fused=fused, qtile=qtile,
-        in_place=in_place)
+        in_place=in_place, scope=scope)
     if before is not None:
         # tracing (if any) happened synchronously during the call above,
         # so the cache-size delta is already visible here
@@ -484,7 +515,9 @@ def delta_topk(q, lq, dx, dlw, dxn, tomb, count: int, *, k: int,
 
     Returns (vals [Q, k] asc, slot [Q, k] int32 delta slots; slot ==
     capacity ⇒ empty).  The caller adds the base cardinality to turn slots
-    into global stream ids (``merge_topk`` does this in-program).
+    into global stream ids (``merge_topk`` does this in-program).  Every
+    stage runs under the ``delta.scan`` scope, apart from the base scan's
+    ``scan.*`` stages in a profile.
     """
     cap = dx.shape[0]
     Q = q.shape[0]
@@ -499,7 +532,7 @@ def delta_topk(q, lq, dx, dlw, dxn, tomb, count: int, *, k: int,
                                   dtype=dtype, scales=scales, zeros=zeros,
                                   rerank=rerank, rerank_norms=rerank_norms,
                                   kprime=kprime, fused=fused, qtile=qtile,
-                                  in_place=in_place)
+                                  in_place=in_place, scope="delta.scan")
     return vals, pos
 
 

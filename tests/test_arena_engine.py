@@ -24,6 +24,7 @@ import pytest
 
 from repro.core import (LabelHybridEngine, LabelWorkloadConfig,
                         generate_label_sets, generate_query_label_sets)
+from repro.core.engine import held_capacity
 from repro.core.labels import encode_many, masks_to_int32_words
 from repro.index.base import (ROW_ID_DTYPE, as_row_ids,
                               check_global_id_contract, pow2_bucket)
@@ -68,14 +69,30 @@ def test_arena_memory_bound(fix):
     assert st.arena_version == 0 and st.delta_nbytes == 0
 
 
+def _held(se, n_rows: int, entries: int) -> tuple[int, int]:
+    """The streaming engine's held arena rows and row-table length
+    (``ShapeReserve``): at least what they hold, at most that plus their
+    headroom, rounded up to a byte of tombstone bits."""
+    from repro.core.engine import ShapeReserve
+    n_held, r_held = se.base.arena.n, se.base.reserve.rows
+    assert n_held == se.base.reserve.arena
+    assert n_held == held_capacity(0, n_rows, ShapeReserve.ARENA_HEADROOM)
+    assert n_rows <= n_held <= n_rows * (1 + ShapeReserve.ARENA_HEADROOM) + 8
+    assert r_held == held_capacity(0, entries, ShapeReserve.ROW_HEADROOM)
+    assert entries <= r_held <= entries * (1 + ShapeReserve.ROW_HEADROOM) + 8
+    return n_held, r_held
+
+
 def test_streaming_memory_bound(fix):
     """ISSUE 4 satellite: with the delta arena and tombstone bitmaps the
     device bound extends to
 
-        N·(D+W+1)·4 + ⌈N/8⌉  +  Σ|I|·4  +  cap·(D+W+1)·4 + ⌈cap/8⌉
+        N'·(D+W+1)·4 + ⌈N'/8⌉  +  R'·4  +  cap·(D+W+1)·4 + ⌈cap/8⌉
 
     (base arena + its bitmap, CSR segment table, delta arena at its
-    current capacity tier + its bitmap)."""
+    current capacity tier + its bitmap), where the arena's rows N' and
+    the table's length R' are the capacities the engine holds across
+    compactions: N and Σ|I| plus their headroom."""
     from repro.core import StreamingEngine
 
     N, D = fix["N"], fix["D"]
@@ -91,8 +108,9 @@ def test_streaming_memory_bound(fix):
     W = se.base.label_words.shape[1]
     cap = se.delta.capacity
     assert cap == 256                      # 100 rows sit in the first tier
-    bound = (N * (D + W + 1) * 4 + -(-N // 8)
-             + st.total_entries * 4
+    n_held, r_held = _held(se, N, st.total_entries)
+    bound = (n_held * (D + W + 1) * 4 + -(-n_held // 8)
+             + r_held * 4
              + cap * (D + W + 1) * 4 + -(-cap // 8))
     assert st.nbytes <= bound, (st.nbytes, bound)
     assert st.delta_nbytes == cap * (D + W + 1) * 4 + -(-cap // 8)
@@ -103,7 +121,7 @@ def test_streaming_memory_bound(fix):
     cap2 = se.delta.capacity
     # 300 rows pad to a 512 batch tier appended at cursor 100 → tier 1024
     assert cap2 == 1024
-    bound2 = (N * (D + W + 1) * 4 + -(-N // 8) + st2.total_entries * 4
+    bound2 = (n_held * (D + W + 1) * 4 + -(-n_held // 8) + r_held * 4
               + cap2 * (D + W + 1) * 4 + -(-cap2 // 8))
     assert st2.nbytes <= bound2, (st2.nbytes, bound2)
 
@@ -171,7 +189,8 @@ def test_streaming_memory_bound_per_dtype(fix, storage):
     W = se.base.label_words.shape[1]
     cap = se.delta.capacity
     assert cap == 256
-    tb = _tier_bytes(N, D, W, storage)
+    n_held, _ = _held(se, N, st.total_entries)
+    tb = _tier_bytes(n_held, D, W, storage)
     td = _tier_bytes(cap, D, W, storage)
     assert st.delta_nbytes == sum(td.values())
     assert st.codes_nbytes == tb["codes"] + td["codes"]

@@ -93,6 +93,38 @@ def test_delta_merge_carries_its_name():
     assert 'op_name="jit(_merge_topk)/delta.merge/' in text
 
 
+def _delta_program_text(scope):
+    """The compiled delta-scan program (in place over a 256-row delta)
+    under ``scope``, and its optimised HLO with the metadata stripped."""
+    N, D, W, Q = 256, 8, 1, 8
+    f32, i32 = jnp.float32, jnp.int32
+    text = ops._segmented_topk.lower(
+        jnp.zeros((Q, D), f32), jnp.zeros((Q, W), i32),
+        jnp.zeros((N, D), f32), jnp.zeros((N, W), i32), jnp.zeros((N,), f32),
+        None, jnp.zeros((Q,), i32), jnp.full((Q,), N, i32),
+        jnp.zeros((N // 8,), jnp.uint8), k=4, lmax=N, chunk=64,
+        metric="l2", backend="ref", interpret=True, in_place=True,
+        scope=scope).compile().as_text()
+    body = text[text.index("\nENTRY") if "\nENTRY" in text else 0:]
+    return text, re.sub(r", metadata=\{[^}]*\}", "", body)
+
+
+def test_delta_scan_stages_carry_the_delta_scope():
+    """The delta scan runs the base scan's program under ``delta.scan``:
+    every stage of it is told apart from the base scan's in a profile,
+    and the scope is metadata only — the optimised program is the same."""
+    scoped, scoped_hlo = _delta_program_text("delta.scan")
+    plain, plain_hlo = _delta_program_text(None)
+    paths = [n for n in re.findall(r'op_name="([^"]*)"', scoped)
+             if n.startswith("jit(_segmented_topk)/")]
+    assert paths and all(n.startswith("jit(_segmented_topk)/delta.scan/")
+                         for n in paths)
+    for name in ("scan.rows", "scan.label_words", "scan.merge"):
+        assert any(f"/{name}/" in n for n in paths), name
+    assert "delta.scan" not in plain
+    assert scoped_hlo == plain_hlo
+
+
 def test_scopes_leave_the_program_alone(data):
     """The scopes are metadata: a search compiles the programs it
     compiled before, one per span tier, and tracing adds none."""
